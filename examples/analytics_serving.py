@@ -14,55 +14,36 @@ request's own context are verified in batched chunks, emitting several
 tokens per target invocation at byte-identical output — the acceptance
 rate and decode steps saved are printed with the engine stats.
 
-Uses the arch's reduced (smoke) config so it runs on CPU; on TPU pass
---full to serve the full config on the production mesh. `--mesh-shape
-1x2` serves one TP/FSDP-sharded engine on a device mesh (DESIGN.md §15;
-on CPU the devices are forced via XLA_FLAGS before jax initializes) and
+Uses the arch's reduced (smoke) config so it runs on CPU; `--full` serves
+the published width (bf16 params) on whatever accelerator JAX finds.
+`--mesh-shape 1x2` serves one column-parallel sharded engine on a device mesh
+(DESIGN.md §15; on a CPU host that many CPU devices are made) and
 `--replicas 2` runs data-parallel engines behind one shared admission
 queue — rows are byte-identical either way. `--tenants N [--qps R]`
 routes every extraction through the async admission tier (DESIGN.md §16):
 each query runs as its own tenant under weighted fair-share scheduling
 with page-headroom backpressure, and per-tenant token/latency accounting
-prints at the end. `--compilation-cache DIR` persists XLA compilations
-across runs.
+prints at the end. Compilations persist across runs through
+`launch/compile_cache.py` (`JAX_COMPILATION_CACHE_DIR`, else
+`<checkout>/.jax_cache`).
 """
 import argparse
-import os
-import sys
 import time
 
+import jax
 
-def _force_cpu_devices_for_mesh(argv) -> None:
-    # XLA only honours the forced host-device count if it's set before jax
-    # initializes, so this must run ahead of `import jax` when the user
-    # asks for a mesh on a single-device host.
-    if "--mesh-shape" not in argv:
-        return
-    spec = argv[argv.index("--mesh-shape") + 1]
-    need = 1
-    for part in spec.replace(",", "x").split("x"):
-        need *= int(part)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if need > 1 and "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={need}".strip())
-
-
-_force_cpu_devices_for_mesh(sys.argv)
-
-import jax  # noqa: E402
-
-from repro.configs import ARCH_IDS, get_config, get_smoke_config  # noqa: E402
-from repro.core import Filter, Query, Session, conj  # noqa: E402
-from repro.data import lm_data  # noqa: E402
-from repro.data.corpus import make_swde_corpus  # noqa: E402
-from repro.extract.served import ServedExtractor  # noqa: E402
-from repro.index.retriever import TwoLevelRetriever  # noqa: E402
-from repro.launch.mesh import make_serving_mesh, parse_mesh_shape  # noqa: E402
-from repro.models import init_params  # noqa: E402
-from repro.serving.engine import ServingEngine  # noqa: E402
-from repro.serving.frontend import ServingFrontend  # noqa: E402
-from repro.serving.replicas import ReplicaGroup  # noqa: E402
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.core import Filter, Query, Session, conj
+from repro.data import lm_data
+from repro.data.corpus import make_swde_corpus
+from repro.extract.served import ServedExtractor
+from repro.index.retriever import TwoLevelRetriever
+from repro.launch.compile_cache import enable_compilation_cache
+from repro.launch.mesh import make_serving_mesh, parse_mesh_shape
+from repro.models import init_params
+from repro.serving.engine import ServingEngine
+from repro.serving.frontend import ServingFrontend
+from repro.serving.replicas import ReplicaGroup
 
 
 def main():
@@ -79,7 +60,7 @@ def main():
                     help="speculative decoding drafter (DESIGN.md §14)")
     ap.add_argument("--mesh-shape", default=None,
                     help="serve on a (data, model) device mesh, e.g. 1x2 "
-                         "(DESIGN.md §15; forces CPU devices if needed)")
+                         "(DESIGN.md §15; on a CPU host, virtual devices)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="data-parallel engine replicas behind one shared "
                          "queue (DESIGN.md §15)")
@@ -90,10 +71,13 @@ def main():
     ap.add_argument("--qps", type=float, default=0.0,
                     help="with --tenants: stagger query arrivals at this "
                          "rate instead of submitting all at once")
-    ap.add_argument("--compilation-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation cache directory — "
-                         "repeat runs skip XLA recompiles")
     args = ap.parse_args()
+    if args.mesh_shape is not None:
+        # CPU hosts get as many (virtual) CPU devices as the mesh needs; the
+        # option touches only the CPU backend and must precede its start
+        n_data, n_model = parse_mesh_shape(args.mesh_shape)
+        jax.config.update("jax_num_cpu_devices", n_data * n_model)
+    enable_compilation_cache()
 
     cfg = (get_config if args.full else get_smoke_config)(args.arch)
     cfg = cfg.replace(vocab_size=max(cfg.vocab_size, lm_data.VOCAB))
@@ -108,14 +92,12 @@ def main():
         engine = ReplicaGroup(cfg, params, replicas=args.replicas,
                               slots=args.slots, max_len=1024,
                               prefix_cache=not args.no_prefix_cache,
-                              spec_decode=args.spec_decode, mesh=mesh,
-                              compilation_cache_dir=args.compilation_cache)
+                              spec_decode=args.spec_decode, mesh=mesh)
         print(f"{args.replicas} engine replicas behind one shared queue")
     else:
         engine = ServingEngine(cfg, params, slots=args.slots, max_len=1024,
                                prefix_cache=not args.no_prefix_cache,
-                               spec_decode=args.spec_decode, mesh=mesh,
-                               compilation_cache_dir=args.compilation_cache)
+                               spec_decode=args.spec_decode, mesh=mesh)
 
     frontend = None
     if args.tenants > 0:

@@ -40,10 +40,7 @@ def _partial_attn(axis, q, k_shard, v_shard, length):
     axes = axis if isinstance(axis, tuple) else (axis,)
     idx = 0
     for a in axes:
-        # jax.lax.axis_size is 0.5+; psum(1, axis) is the 0.4.x spelling
-        size = (jax.lax.axis_size(a) if hasattr(jax.lax, "axis_size")
-                else jax.lax.psum(1, a))
-        idx = idx * size + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     s_loc = k_shard.shape[1]
     start = idx * s_loc
     s = jnp.einsum("bqhgd,bshd->bhgqs", q, k_shard,

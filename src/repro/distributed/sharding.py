@@ -12,6 +12,14 @@ Policy (DESIGN.md §4):
     path); SSM state: d_inner over model.
   - optimizer state mirrors its parameter's spec (extra leading quant-block
     dims for adam8bit replicate).
+
+Serving (`serving=True`, DESIGN.md §15) uses a column-parallel layout
+instead: the dense projections shard only dims they do not contract, over
+`model`, and replicate over `data` (slot batch parallelism); the
+activations around the output projections (`wo`, `w_down`) are gathered
+("gather" constraint), so norms also reduce whole rows. No dot or norm
+then reduces across devices, no partial sums meet in an all-reduce, and a
+bf16 model decodes exactly the tokens it decodes on one device.
 """
 from __future__ import annotations
 
@@ -27,22 +35,12 @@ from repro.launch.mesh import batch_axes
 FSDP = "data"
 TP = "model"
 
-# jax >= 0.5 exposes shard_map at the top level with axis_names/check_vma;
-# 0.4.x has it under experimental with the complementary auto=/check_rep=
-# spelling. The wrapper accepts the new-style call and translates.
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma=True):
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # 0.4.x partial-auto lowers to PartitionId ops the SPMD partitioner
-    # rejects; run fully manual instead — axes the specs don't mention are
-    # replicated per device, numerically identical (just unpartitioned),
-    # which needs the replication check off.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """`jax.shard_map`, manual over `axis_names` (None = every mesh axis)."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 # trailing-dim roles per leaf name: 'f' = FSDP(data), 't' = TP(model),
 # '.' = replicated. Leading dims (layer stacks etc.) always replicate.
@@ -71,6 +69,15 @@ _ROLES = {
     "w1": "f.", "w2": "f.",                   # mm projector
     "a_q": "f.", "a_k": "f.", "a_v": "f.", "a_o": "f.",
     "b_q": ".t", "b_k": ".t", "b_v": ".t", "b_o": "..",
+}
+
+# serving overrides: column-parallel over `model` only ('t' on a dim the
+# weight's matmul never contracts); the embedding shards its vocab, so the
+# lookup's cross-device sum adds exact zeros
+SERVING_ROLES = {
+    "embed": "t.", "lm_head": ".t",
+    "wq": ".t.", "wk": ".t.", "wv": ".t.", "wo": "..t",
+    "w_gate": ".t", "w_up": ".t", "w_in": ".t", "w_down": ".t",
 }
 
 
@@ -115,9 +122,11 @@ def param_specs(cfg: ModelConfig, params_shape, mesh, overrides=None):
     return jax.tree_util.tree_unflatten(treedef, specs)
 
 
-def param_shardings(cfg: ModelConfig, params_shape, mesh):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s),
-                        param_specs(cfg, params_shape, mesh))
+def param_shardings(cfg: ModelConfig, params_shape, mesh, *,
+                    serving: bool = False):
+    specs = param_specs(cfg, params_shape, mesh,
+                        SERVING_ROLES if serving else None)
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
 
 def opt_state_specs(cfg: ModelConfig, opt_shape, pspecs, mesh):
@@ -179,17 +188,20 @@ def batch_spec(mesh, batch_size: int) -> tuple:
     return tuple(axes) if axes else ()
 
 
-def make_constrain(mesh, batch_size: int, *, ep_moe: bool = False):
+def make_constrain(mesh, batch_size: int, *, ep_moe: bool = False,
+                   serving: bool = False):
     """Activation sharding hook threaded through model forward/decode.
 
     ep_moe: pin MoE dispatch/combine buffers (E, C, d) to P(data, None, None)
     — experts live on data shards, so GSPMD moves *tokens* (all-to-all)
-    instead of all-gathering index tensors and reducing dispatch products."""
+    instead of all-gathering index tensors and reducing dispatch products.
+    serving: gather the activations around the output projections
+    ("gather") for the column-parallel serving layout; a no-op otherwise."""
     baxes = batch_spec(mesh, batch_size)
     b = baxes if baxes else None
 
     def constrain(x, kind):
-        if kind == "hidden":
+        if kind == "hidden" or (kind == "gather" and serving):
             spec = P(b, *([None] * (x.ndim - 1)))
         elif kind == "logits":
             spec = P(b, *([None] * (x.ndim - 2)), TP)

@@ -46,6 +46,8 @@ class ServedStats:
     draft_tokens: int = 0              # speculative decode (DESIGN.md §14):
     accepted_tokens: int = 0           # drafted/accepted tokens and decode
     decode_steps_saved: int = 0        # steps saved for our requests
+    parses: int = 0                    # decoded answers parsed, and how many
+    parse_fallbacks: int = 0           # fell back to the oracle (§8.1)
 
 
 class ServedExtractor:
@@ -204,7 +206,9 @@ class ServedExtractor:
     def _parse(self, doc_id, attr: str, answer: str, context: str):
         spec = self._spec(doc_id, attr)
         value = spec.parse(answer) if spec else None
+        self.stats.parses += 1
         if value is None and self.oracle_fallback and spec is not None:
+            self.stats.parse_fallbacks += 1
             value = spec.parse(context)         # DESIGN.md §8.1 split
         return value
 

@@ -6,7 +6,7 @@ what the two-level index and evidence augmentation exploit; every method in
 the benchmarks shares this embedder so comparisons stay controlled.
 
 Batched feature->embedding projection runs under jit (it is also the math
-the `topk_l2` Pallas kernel consumes at corpus scale).
+the `l2_rank` device ranking consumes at corpus scale).
 """
 from __future__ import annotations
 
@@ -59,7 +59,9 @@ class HashedEmbedder:
         return self
 
     def _project_fn(self, feats):
-        emb = feats @ self._proj
+        # full float32: the TPU default would round the operands to bf16,
+        # and the index would then differ between backends
+        emb = jnp.dot(feats, self._proj, precision=jax.lax.Precision.HIGHEST)
         norm = jnp.linalg.norm(emb, axis=-1, keepdims=True)
         return emb / jnp.maximum(norm, 1e-6)
 

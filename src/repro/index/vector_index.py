@@ -6,19 +6,64 @@ is the scalable variant used at corpus scale. Both expose the same batched
 contract — `search` (top-k), `range_search` (distance threshold tau/gamma),
 and `range_search_many` (one fused pass over a probe batch, the API the
 cross-document scheduler's `prefetch_segments` drives) — so either can back
-a `TwoLevelRetriever` store. The hot loop delegates to
-`repro.kernels.ops.topk_l2` (Pallas on TPU, jnp elsewhere).
+a `TwoLevelRetriever` store. The hot loop is `l2_rank`: one jitted XLA
+program (distance matmul + rank) that runs unchanged on every backend.
 """
 from __future__ import annotations
 
+from functools import partial
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .kmeans import kmeans
 
+# device ranking pads rows/queries to pow2 buckets so a growing store
+# reuses a handful of compiled programs instead of one per size
+_ROW_BUCKET, _QUERY_BUCKET = 256, 8
 
-def _topk_l2(db: np.ndarray, q: np.ndarray, k: int):
-    from repro.kernels import ops
-    return ops.topk_l2(db, q, k)
+
+def _bucket(n: int, floor: int) -> int:
+    b = floor
+    while b < n:
+        b <<= 1
+    return b
+
+
+@partial(jax.jit, static_argnums=3)
+def l2_rank_device(db, q, n, k):
+    """db (Np, D) with rows >= n padding; q (Mp, D). Squared distances come
+    from one matmul at full float32 precision (the TPU default would round
+    the operands to bf16 and reorder near neighbours); padding ranks last.
+    k == Np is the full ranking (stable argsort), otherwise lax.top_k —
+    both put equal distances in index order."""
+    d2 = (jnp.sum(q * q, axis=1)[:, None] + jnp.sum(db * db, axis=1)[None, :]
+          - 2.0 * jnp.dot(q, db.T, precision=jax.lax.Precision.HIGHEST))
+    valid = jnp.arange(db.shape[0])[None, :] < n
+    d2 = jnp.where(valid, jnp.maximum(d2, 0.0), jnp.inf)
+    if k == db.shape[0]:
+        idx = jnp.argsort(d2, axis=1, stable=True)
+    else:
+        _, idx = jax.lax.top_k(-d2, k)
+    return jnp.sqrt(jnp.take_along_axis(d2, idx, axis=1)), idx
+
+
+def l2_rank(db: np.ndarray, q: np.ndarray, k: int | None = None):
+    """The k nearest rows of `db` (N, D) to each query of `q` (M, D) by L2
+    distance, ascending, ties in index order; k=None (or k >= N) ranks all
+    N. Returns numpy (dists (M, k), idx (M, k))."""
+    db = np.asarray(db, np.float32)
+    q = np.atleast_2d(np.asarray(q, np.float32))
+    (n, dim), m = db.shape, len(q)
+    k = n if k is None else min(int(k), n)
+    n_pad, m_pad = _bucket(n, _ROW_BUCKET), _bucket(m, _QUERY_BUCKET)
+    db_p = np.zeros((n_pad, dim), np.float32)
+    db_p[:n] = db
+    q_p = np.zeros((m_pad, dim), np.float32)
+    q_p[:m] = q
+    dists, idx = l2_rank_device(db_p, q_p, n, n_pad if k == n else k)
+    return np.asarray(dists)[:m, :k], np.asarray(idx)[:m, :k]
 
 
 def _live_distance(emb: np.ndarray, ids: list, dead: np.ndarray,
@@ -105,7 +150,7 @@ class ExactIndex:
         # over-fetch by the tombstone count so dead rows can never displace
         # live ones from the top-k, then filter per row
         kk = min(k + self._n_dead, len(self.ids))
-        dists, idx = _topk_l2(self.emb, q, kk)
+        dists, idx = l2_rank(self.emb, q, kk)
         out = []
         for row_d, row_i in zip(np.asarray(dists), np.asarray(idx)):
             if self._n_dead:
@@ -116,16 +161,15 @@ class ExactIndex:
 
     def _ranked(self, qs: np.ndarray):
         """Full ascending ranking per query: (dists (M, N), idx (M, N)).
-        Large databases go through the `kernels.topk_l2` kernel with k = N
-        (same gate as kernels.ops.topk_l2); small ones use a numpy
-        broadcast. Serial and batched range search share this helper, so
-        they agree per query at every database size."""
-        if len(self.ids) >= 256:
-            dists, idx = _topk_l2(self.emb, qs, len(self.ids))
-            return np.asarray(dists), np.asarray(idx)
+        Databases of 256 rows and more are ranked on the device by
+        `l2_rank`; smaller ones by a numpy broadcast. Serial and batched
+        range search share this helper, so they agree per query at every
+        database size."""
+        if len(self.ids) >= _ROW_BUCKET:
+            return l2_rank(self.emb, qs)
         d = np.sqrt(np.maximum(
             ((self.emb[None] - qs[:, None]) ** 2).sum(-1), 0.0))
-        idx = np.argsort(d, axis=1)
+        idx = np.argsort(d, axis=1, kind="stable")
         return np.take_along_axis(d, idx, axis=1), idx
 
     def range_search(self, q: np.ndarray, tau: float):
@@ -160,7 +204,7 @@ class IVFIndex:
 
     Approximate; recall controlled by nprobe. Used for corpus-scale document/
     segment stores (paper cites PQ/HNSW — IVF is the TPU-friendly choice: the
-    probed lists become dense tiles for the topk_l2 kernel)."""
+    probed lists become dense tiles for the `l2_rank` matmul)."""
 
     def __init__(self, embeddings: np.ndarray, ids: list | None = None,
                  n_lists: int = 16, nprobe: int = 4, seed: int = 0, *,
@@ -295,20 +339,19 @@ class IVFIndex:
 
     def _ranked_rows(self, q: np.ndarray):
         """Probed rows of one query, ranked ascending by distance: (rows,
-        dists). Large probe sets go through the `kernels.topk_l2` kernel
-        with k = |probed| (the same gate as `ExactIndex._ranked`); small
-        ones use a numpy broadcast. `search`/`range_search`/
-        `range_search_many` all share this helper."""
+        dists). Large probe sets are ranked by `l2_rank` (the same gate as
+        `ExactIndex._ranked`); small ones by a numpy broadcast. `search`/
+        `range_search`/`range_search_many` all share this helper."""
         rows = self._probe(q)
         if not len(rows):
             return rows, np.zeros((0,), np.float32)
         sub = self.emb[rows]
-        if len(rows) >= 256:
-            dists, idx = _topk_l2(sub, q[None], len(rows))
-            d, order = np.asarray(dists)[0], np.asarray(idx)[0]
+        if len(rows) >= _ROW_BUCKET:
+            dists, idx = l2_rank(sub, q[None])
+            d, order = dists[0], idx[0]
         else:
             d = np.sqrt(np.maximum(((sub - q[None]) ** 2).sum(-1), 0.0))
-            order = np.argsort(d)
+            order = np.argsort(d, kind="stable")
             d = d[order]
         return rows[order], d
 

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -133,7 +132,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, lengths, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(lengths, jnp.int32), jnp.asarray(page_table, jnp.int32),
@@ -222,7 +221,7 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, page_table, starts, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, C, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(starts, jnp.int32), jnp.asarray(page_table, jnp.int32),
@@ -302,7 +301,7 @@ def decode_attention_pallas(q, k_cache, v_cache, length, *, bk=512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q, k_cache, v_cache)

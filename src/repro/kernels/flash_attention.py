@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -98,7 +97,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, bq=128, bk=128,
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 NEG = -1e30
 
@@ -60,7 +59,7 @@ def moe_gating_pallas(logits, k: int, *, bt=256, interpret=False):
                    pl.BlockSpec((bt, k), lambda t: (t, 0))],
         out_shape=[jax.ShapeDtypeStruct((Tp, k), jnp.float32),
                    jax.ShapeDtypeStruct((Tp, k), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(logits)

@@ -4,15 +4,12 @@ Backend selection: "pallas" lowers the Pallas TPU kernels (interpret=True on
 CPU so the same kernel body is validated in this container); "xla" runs the
 mathematically identical jnp path (used by the distributed dry-run, where
 Pallas-for-CPU cannot be compiled ahead-of-time). Default: xla on CPU,
-pallas on TPU.
+pallas on TPU. No model or index path calls these wrappers yet (ROADMAP D1);
+retrieval ranks with `index.vector_index.l2_rank`, plain XLA everywhere.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from . import ref
 
@@ -32,24 +29,6 @@ def backend() -> str:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-# ------------------------------------------------------------- topk_l2 -----
-
-
-@functools.partial(jax.jit, static_argnums=(2,))
-def _topk_l2_xla(db, q, k):
-    return ref.topk_l2_ref(db, q, k)
-
-
-def topk_l2(db, q, k: int):
-    """Top-k nearest (L2) database rows per query. db: (N,D), q: (M,D)."""
-    db = jnp.asarray(db, jnp.float32)
-    q = jnp.asarray(q, jnp.float32)
-    if backend() == "pallas" and db.shape[0] >= 256:
-        from .topk_l2 import topk_l2_pallas
-        return topk_l2_pallas(db, q, k, interpret=_interpret())
-    return _topk_l2_xla(db, q, k)
 
 
 # ------------------------------------------------------ flash attention ----

@@ -37,13 +37,6 @@ def decode_attention_ref(q, k_cache, v_cache, length):
     return out.reshape(B, H, D)
 
 
-def topk_l2_ref(db, q, k: int):
-    """db: (N, D); q: (M, D). Returns (dists (M,k), idx (M,k)) ascending."""
-    d2 = jnp.sum((q[:, None, :] - db[None, :, :]) ** 2, axis=-1)
-    neg, idx = jax.lax.top_k(-d2, k)
-    return jnp.sqrt(jnp.maximum(-neg, 0.0)), idx
-
-
 def ssm_scan_ref(x, dt, A, B_mat, C_mat, D, h0=None):
     """Mamba1 selective scan oracle. Shapes as repro.models.ssm.mamba1_scan_ref."""
     from repro.models.ssm import mamba1_scan_ref

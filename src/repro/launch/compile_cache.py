@@ -1,62 +1,33 @@
-"""Persistent JAX compilation cache (ROADMAP open item 5, first cut).
+"""Persistent JAX compilation cache for the entry points.
 
-Repeated bench/serving runs over the smoke models re-pay jit compilation
-on every process start — for the tiny configs the compile wall dominates
-the compute wall. JAX ships a persistent on-disk compilation cache that
-keys compiled executables by (HLO, jaxlib version, backend); enabling it
-makes the second run of the same bench skip re-jit entirely.
+A cold full-width run compiles one chunked-prefill program per context
+width, a decode step per context width and a verify step per `n_ctx`, each
+a whole-model program; the persistent cache lets the next process on the
+same machine skip them. Entry points (`chip_smoke.py`,
+`examples/analytics_serving.py`) call `enable_compilation_cache()` once,
+before anything is jitted.
 
-`enable_compilation_cache(dir)` turns it on for the current process,
-dropping the default entry-size/compile-time floors so even the smoke
-configs' sub-second compiles are cached (the floors exist to keep
-production caches small; a bench cache wants everything). Exposed as the
-`compilation_cache_dir=` knob on `ServingEngine` and as
-`--compilation-cache` on the bench/example drivers.
-
-Safe to call more than once (idempotent per directory) and a no-op on jax
-builds without the config knobs — callers never have to guard it.
+Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and nothing
+is set here. Otherwise the cache lives at `<checkout>/.jax_cache`: a fixed
+path, never a temp name, since a directory that moves between runs never
+hits.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
+from pathlib import Path
 
-_enabled_dir: Optional[str] = None
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_compilation_cache(cache_dir: str, *,
-                             min_entry_size_bytes: int = 0,
-                             min_compile_time_secs: float = 0.0) -> bool:
-    """Point jax's persistent compilation cache at `cache_dir` (created if
-    missing). Returns True when the cache is active, False when this jax
-    build lacks the knobs. Subsequent calls with the same directory are
-    no-ops; a different directory re-points the cache."""
-    global _enabled_dir
+def enable_compilation_cache() -> str:
+    """Turn the persistent cache on for this process; returns its
+    directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
 
-    cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
-    if _enabled_dir == cache_dir:
-        return True
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_enable_compilation_cache", True)
-    except (AttributeError, ValueError, OSError):
-        return False
-    # floors default to 'worth persisting in production'; benches want the
-    # tiny smoke-model compiles cached too, so drop them to the caller's
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes",
-                       int(min_entry_size_bytes)),
-                      ("jax_persistent_cache_min_compile_time_secs",
-                       float(min_compile_time_secs))):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):
-            pass  # older jax: the cache still works with default floors
-    _enabled_dir = cache_dir
-    return True
-
-
-def compilation_cache_dir() -> Optional[str]:
-    """The directory the persistent cache was enabled with (None = off)."""
-    return _enabled_dir
+    CACHE_DIR.mkdir(exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)

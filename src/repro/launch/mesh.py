@@ -9,19 +9,29 @@ per step, optionally int8-compressed), `model` stays intra-pod on ICI.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes, devices=None):
+    """Every mesh here has Auto axes: the sharding rules place arrays with
+    NamedSharding/with_sharding_constraint and leave the rest to GSPMD.
+    (jax.make_mesh defaults to Explicit axes, under which an un-annotated
+    gather such as the embedding lookup is a ShardingTypeError.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, *, multi_pod: bool = False):
     """Small mesh for CPU tests (requires XLA_FLAGS host device override)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _make_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return _make_mesh((n_data, n_model), ("data", "model"))
 
 
 def parse_mesh_shape(spec) -> tuple:
@@ -50,7 +60,7 @@ def make_serving_mesh(shape=(1, 2)):
             f"{have}; on CPU launch with XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} (must be set "
             f"before jax initializes)")
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _make_mesh((n_data, n_model), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -86,6 +86,18 @@ def activation_fn(name: str):
     raise ValueError(f"unknown activation {name}")
 
 
+# ----------------------------------------------------------- projections --
+
+
+def proj(spec: str, x, w):
+    """Weight projection: `einsum(spec, x, w)` accumulated in float32 and
+    rounded once to the operands' dtype. A mesh that shards the contracted
+    dim (TP output projections, FSDP) then all-reduces float32 partial sums,
+    so a bf16 model decodes the same tokens sharded as on one device."""
+    out = jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
+    return out.astype(jnp.result_type(x.dtype, w.dtype))
+
+
 # ------------------------------------------------------------------ mlp ----
 
 
@@ -102,13 +114,20 @@ def mlp_init(cfg: ModelConfig, key, d_ff: int | None = None):
     return p
 
 
-def mlp_apply(cfg: ModelConfig, p, x):
+def no_constrain(x, kind):
+    """The default sharding hook (one device): leaves `x` as it is."""
+    return x
+
+
+def mlp_apply(cfg: ModelConfig, p, x, constrain=no_constrain):
     act = activation_fn(cfg.activation)
     if cfg.gated_mlp:
-        h = act(x @ p["w_gate"]) * (x @ p["w_up"])
+        h = act(proj("...d,df->...f", x, p["w_gate"])) * \
+            proj("...d,df->...f", x, p["w_up"])
     else:
-        h = act(x @ p["w_in"])
-    return h @ p["w_down"]
+        h = act(proj("...d,df->...f", x, p["w_in"]))
+    return constrain(proj("...f,fd->...d", constrain(h, "gather"), p["w_down"]),
+                     "gather")
 
 
 # ------------------------------------------------------------ attention ----
@@ -271,9 +290,9 @@ def lora_init(cfg: ModelConfig, key, n_app: int):
 def _project_qkv(cfg: ModelConfig, p, x, lora=None):
     B, S, d = x.shape
     nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
-    k = jnp.einsum("bsd,dhe->bshe", x, p["wk"])
-    v = jnp.einsum("bsd,dhe->bshe", x, p["wv"])
+    q = proj("bsd,dhe->bshe", x, p["wq"])
+    k = proj("bsd,dhe->bshe", x, p["wk"])
+    v = proj("bsd,dhe->bshe", x, p["wv"])
     if lora is not None:
         q = q + ((x @ lora["a_q"]) @ lora["b_q"]).reshape(B, S, nq, hd)
         k = k + ((x @ lora["a_k"]) @ lora["b_k"]).reshape(B, S, nkv, hd)
@@ -287,7 +306,8 @@ def _project_qkv(cfg: ModelConfig, p, x, lora=None):
 
 
 def attn_apply(cfg: ModelConfig, p, x, *, positions, causal=True, lora=None,
-               kv_override=None, block_threshold=8192):
+               kv_override=None, block_threshold=8192,
+               constrain=no_constrain):
     """Full-sequence self-attention (train / prefill). Returns (out, (k, v)).
 
     kv_override: (k, v) for cross-attention (already projected+rotated).
@@ -300,7 +320,7 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, causal=True, lora=None,
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
     else:
-        q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
+        q = proj("bsd,dhe->bshe", x, p["wq"])
         if cfg.attn_bias:
             q = q + p["bq"]
         k, v = kv_override
@@ -314,7 +334,8 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, causal=True, lora=None,
     else:
         out = blockwise_attention(qg, k, v, causal=causal)
     out = out.reshape(B, S, nq, hd)
-    out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
+    out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"), p["wo"]),
+                    "gather")
     if lora is not None:
         flat = out  # LoRA on output proj applied to attention output
         out = out + (flat @ lora["a_o"]) @ lora["b_o"]
@@ -363,7 +384,7 @@ def chunk_positions(start, B: int, C: int):
 
 
 def attn_chunk_apply(cfg: ModelConfig, p, x, *, start, k_cache, v_cache,
-                     lora=None, cross=False):
+                     lora=None, cross=False, constrain=no_constrain):
     """Chunked-prefill attention: C query tokens at positions
     [start, start+C) attend the cache up to their own position (causal
     within the chunk, full over the already-filled prefix). Generalizes
@@ -379,7 +400,7 @@ def attn_chunk_apply(cfg: ModelConfig, p, x, *, start, k_cache, v_cache,
     nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     q_pos = chunk_positions(start, B, C)                      # (B, C)
     if cross:
-        q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
+        q = proj("bsd,dhe->bshe", x, p["wq"])
         if cfg.attn_bias:
             q = q + p["bq"]
     else:
@@ -399,14 +420,16 @@ def attn_chunk_apply(cfg: ModelConfig, p, x, *, start, k_cache, v_cache,
     pr = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqs,bshd->bqhgd", pr.astype(v_cache.dtype), v_cache)
     out = out.reshape(B, C, nq, hd)
-    out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
+    out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"), p["wo"]),
+                    "gather")
     if lora is not None:
         out = out + (out @ lora["a_o"]) @ lora["b_o"]
     return out, (k_cache, v_cache) if not cross else (None, None)
 
 
 def attn_decode_apply(cfg: ModelConfig, p, x, *, pos, k_cache, v_cache, lora=None,
-                      cross=False, cache_len=None, attn_impl=None):
+                      cross=False, cache_len=None, attn_impl=None,
+                      constrain=no_constrain):
     """Single-token decode. x: (B, 1, d). Caches (B, Smax, Hkv, hd).
     `pos` may be a scalar or a per-row (B,) vector (continuous batching).
 
@@ -416,7 +439,7 @@ def attn_decode_apply(cfg: ModelConfig, p, x, *, pos, k_cache, v_cache, lora=Non
     B, S, _ = x.shape
     nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     if cross:
-        q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
+        q = proj("bsd,dhe->bshe", x, p["wq"])
         if cfg.attn_bias:
             q = q + p["bq"]
         k_new = v_new = None
@@ -436,7 +459,8 @@ def attn_decode_apply(cfg: ModelConfig, p, x, *, pos, k_cache, v_cache, lora=Non
     impl = attn_impl or decode_attention
     out = impl(qg, k_cache, v_cache, length)
     out = out.reshape(B, S, nq, hd)
-    out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
+    out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"), p["wo"]),
+                    "gather")
     if lora is not None:
         out = out + (out @ lora["a_o"]) @ lora["b_o"]
     return out, (k_cache, v_cache) if not cross else (None, None)

@@ -32,8 +32,13 @@ def _cast(params, dtype):
     return jax.tree.map(c, params)
 
 
-def _id_constrain(x, kind):  # default no-op sharding hook
-    return x
+def _logits(cfg, x, head):
+    """LM head accumulated in float32 and returned in `cfg.logit_dtype`:
+    greedy argmax over a bf16 vocabulary would tie (and flip between
+    sharded and single-device runs) far more often."""
+    out = jnp.einsum("...d,dv->...v", x, head,
+                     preferred_element_type=jnp.float32)
+    return out.astype(cfg.logit_dtype)
 
 
 # ------------------------------------------------------------------ init ----
@@ -61,7 +66,15 @@ def _stack_init(init_fn, key, n):
     return jax.vmap(init_fn)(keys)
 
 
+@partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key):
+    """Random params with every floating leaf in `cfg.dtype`. The draw is
+    float32 and the cast happens inside this one jitted program, so a bf16
+    deployment never holds a float32 copy of the tree on the device."""
+    return _cast(_init_params_f32(cfg, key), cfg.dtype)
+
+
+def _init_params_f32(cfg: ModelConfig, key):
     ks = jax.random.split(key, 8)
     p = {"embed": jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model), jnp.float32) * 0.02,
          "final_norm": L.norm_init(cfg, cfg.d_model)}
@@ -118,13 +131,14 @@ def _dense_block(cfg, lp, x, positions, constrain, *, lora=None, causal=True):
     if cfg.use_mla:
         a, kv = L.mla_apply(cfg, lp["attn"], h, positions=positions)
     else:
-        a, kv = L.attn_apply(cfg, lp["attn"], h, positions=positions, causal=causal, lora=lora)
+        a, kv = L.attn_apply(cfg, lp["attn"], h, positions=positions, causal=causal,
+                             lora=lora, constrain=constrain)
     x = constrain(x + a, "hidden")
     h = L.norm_apply(cfg, lp["mlp_norm"], x)
     if "moe" in lp:
         m, aux = L.moe_apply(cfg, lp["moe"], h, return_aux=True, constrain=constrain)
     else:
-        m, aux = L.mlp_apply(cfg, lp["mlp"], h), jnp.float32(0.0)
+        m, aux = L.mlp_apply(cfg, lp["mlp"], h, constrain), jnp.float32(0.0)
     return constrain(x + m, "hidden"), kv, aux
 
 
@@ -210,7 +224,7 @@ def forward(cfg: ModelConfig, params, batch, *, remat=False, constrain=None,
             return_kv=False, unroll=False):
     """Full-sequence forward. Returns (logits, aux_loss) — logits (B, S, V)
     over *text* positions (vlm: image positions excluded)."""
-    constrain = constrain or _id_constrain
+    constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     tokens = batch["tokens"]
     B = tokens.shape[0]
@@ -260,7 +274,7 @@ def forward(cfg: ModelConfig, params, batch, *, remat=False, constrain=None,
     if n_img:
         x = x[:, n_img:]
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-    logits = constrain(x @ head, "logits")
+    logits = constrain(_logits(cfg, x, head), "logits")
     return logits, aux
 
 
@@ -316,7 +330,7 @@ def init_decode_cache(cfg: ModelConfig, B: int, max_len: int, dtype=None):
 def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
                 attn_impl=None, unroll=False):
     """One decode step. token: (B, 1) int32. Returns (logits (B,1,V), cache)."""
-    constrain = constrain or _id_constrain
+    constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     pos = cache["pos"]
     x = jnp.take(p["embed"], token, axis=0)
@@ -327,7 +341,8 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
     def attn_block(lp, h, kc, vc, lora=None, cross_kv=None):
         hh = L.norm_apply(cfg, lp["attn_norm"], h)
         a, (kc, vc) = L.attn_decode_apply(cfg, lp["attn"], hh, pos=pos, k_cache=kc,
-                                          v_cache=vc, lora=lora, attn_impl=attn_impl)
+                                          v_cache=vc, lora=lora, attn_impl=attn_impl,
+                                          constrain=constrain)
         h = h + a
         if cross_kv is not None:
             hh = L.norm_apply(cfg, lp["cross_norm"], h)
@@ -339,7 +354,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
         if "moe" in lp:
             h = h + L.moe_apply(cfg, lp["moe"], hh, constrain=constrain)
         else:
-            h = h + L.mlp_apply(cfg, lp["mlp"], hh)
+            h = h + L.mlp_apply(cfg, lp["mlp"], hh, constrain)
         return h, kc, vc
 
     scan = lambda f, init, xs: lax.scan(f, init, xs, unroll=unroll)
@@ -433,7 +448,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
 
     x = L.norm_apply(cfg, p["final_norm"], x)
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-    logits = constrain(x @ head, "logits")
+    logits = constrain(_logits(cfg, x, head), "logits")
     new_cache["pos"] = pos + 1
     return logits, new_cache
 
@@ -467,7 +482,7 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
     cache["ck"]/["cv"] already populated (see `encode_cross_kv`).
     Returns (last-position logits (B, 1, V), new cache).
     """
-    constrain = constrain or _id_constrain
+    constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     pos = cache["pos"]
     tokens = batch["tokens"]
@@ -488,7 +503,8 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
     def attn_block(lp, h, kc, vc, lora=None, cross_kv=None):
         hh = L.norm_apply(cfg, lp["attn_norm"], h)
         a, (kc, vc) = L.attn_chunk_apply(cfg, lp["attn"], hh, start=start,
-                                         k_cache=kc, v_cache=vc, lora=lora)
+                                         k_cache=kc, v_cache=vc, lora=lora,
+                                         constrain=constrain)
         h = h + a
         if cross_kv is not None:
             hh = L.norm_apply(cfg, lp["cross_norm"], h)
@@ -500,7 +516,7 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
         if "moe" in lp:
             h = h + L.moe_apply(cfg, lp["moe"], hh, constrain=constrain)
         else:
-            h = h + L.mlp_apply(cfg, lp["mlp"], hh)
+            h = h + L.mlp_apply(cfg, lp["mlp"], hh, constrain)
         return h, kc, vc
 
     if fam in ("dense", "vlm", "moe"):
@@ -602,7 +618,7 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
         adv = n_img + length
     x = L.norm_apply(cfg, p["final_norm"], x_last)
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-    logits = constrain(x @ head, "logits")
+    logits = constrain(_logits(cfg, x, head), "logits")
     new_cache["pos"] = pos + adv
     return logits, new_cache
 
@@ -632,7 +648,7 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
     at its own decode position. Returns (logits (B, C, V), new_cache, ckpts).
     Rows are independent; callers discard rows/suffixes they reject.
     """
-    constrain = constrain or _id_constrain
+    constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     pos = cache["pos"]
     tokens = batch["tokens"]
@@ -648,7 +664,8 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
     def attn_block(lp, h, kc, vc, lora=None, cross_kv=None):
         hh = L.norm_apply(cfg, lp["attn_norm"], h)
         a, (kc, vc) = L.attn_chunk_apply(cfg, lp["attn"], hh, start=start,
-                                         k_cache=kc, v_cache=vc, lora=lora)
+                                         k_cache=kc, v_cache=vc, lora=lora,
+                                         constrain=constrain)
         h = h + a
         if cross_kv is not None:
             hh = L.norm_apply(cfg, lp["cross_norm"], h)
@@ -660,7 +677,7 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
         if "moe" in lp:
             h = h + L.moe_apply(cfg, lp["moe"], hh, constrain=constrain)
         else:
-            h = h + L.mlp_apply(cfg, lp["mlp"], hh)
+            h = h + L.mlp_apply(cfg, lp["mlp"], hh, constrain)
         return h, kc, vc
 
     if fam in ("dense", "vlm", "moe"):
@@ -761,7 +778,7 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
 
     x = L.norm_apply(cfg, p["final_norm"], x)
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-    logits = constrain(x @ head, "logits")
+    logits = constrain(_logits(cfg, x, head), "logits")
     new_cache["pos"] = pos + C
     return logits, new_cache, ckpts
 
@@ -772,7 +789,7 @@ def encode_cross_kv(cfg: ModelConfig, params, frames, *, constrain=None,
     the encdec prerequisite for `prefill_chunk` (full `prefill` computes
     these inside the decoder blocks). Returns (ck, cv), each
     (num_layers, B, encoder_seq, n_kv_heads, head_dim)."""
-    constrain = constrain or _id_constrain
+    constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     enc_out = _encoder(cfg, p, frames.astype(cfg.dtype), constrain,
                        remat=False, unroll=unroll)
@@ -802,7 +819,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int, length=None, *,
     is never attended, and SSM/conv state is frozen past `length` (padded
     positions get dt=0, the conv tail is sliced at the real boundary).
     """
-    constrain = constrain or _id_constrain
+    constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     tokens = batch["tokens"]
     B = tokens.shape[0]
@@ -944,6 +961,6 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int, length=None, *,
         true_len = n_img + length
     x = L.norm_apply(cfg, p["final_norm"], x_last)
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-    logits = constrain(x @ head, "logits")
+    logits = constrain(_logits(cfg, x, head), "logits")
     cache["pos"] = jnp.asarray(true_len, jnp.int32)
     return logits, cache

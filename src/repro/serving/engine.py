@@ -44,8 +44,9 @@ the economy is reported via `stats["draft_tokens"]` /
 
 Mesh-aware serving (DESIGN.md §15): with `mesh=` set (a (data, model) mesh
 from `launch/mesh.py`, CPU meshes supported for CI), the engine runs every
-phase multi-device: params are laid out with the FSDP+TP rules of
-`distributed/sharding.py`, the decode cache shards its slot axis over
+phase multi-device: params take the column-parallel serving layout of
+`distributed/sharding.py` (no cross-device partial sums, so bf16 rounds
+as on one device), the decode cache shards its slot axis over
 `data` and heads/features over `model`, and the paged KV pool shards pages
 replicated / heads over `model` (page tables stay host-local integers).
 The jitted phases — chunked prefill, paged decode, and spec-decode verify —
@@ -132,6 +133,14 @@ class RunTruncated(RuntimeError):
         self.finished = finished
 
 
+# Every model phase compiles with one rounding point per bf16 op (no excess
+# precision carried through a fusion, whose extent differs from one program
+# shape to the next), so a mesh engine and a one-device engine decode the
+# same tokens.
+_jit_phase = partial(jax.jit,
+                     compiler_options={"xla_allow_excess_precision": False})
+
+
 def _pow2_at_least(n: int) -> int:
     p = 1
     while p < n:
@@ -174,7 +183,6 @@ class ServingEngine:
                  spec_decode="off", spec_k: int = 4, spec_ngram: int = 3,
                  draft_model: Optional[tuple] = None, mesh=None,
                  page_allocator: Optional[PageAllocator] = None,
-                 compilation_cache_dir: Optional[str] = None,
                  tracer=None, metrics=None):
         """queue_depth: optional admission-control bound on queued requests;
         ServedExtractor splits its batch rounds into windows of this size
@@ -198,26 +206,21 @@ class ServingEngine:
         draft_model: (ModelConfig, params) of the draft model, required for
         spec_decode="draft" (dense/moe family, same vocab).
         mesh: optional (data, model) jax Mesh (see `launch/mesh.py`) — run
-        the engine multi-device with FSDP+TP-sharded params, sharded decode
+        the engine multi-device with column-parallel params, sharded decode
         cache / paged KV pool, and mesh-constrained jitted phases (DESIGN.md
         §15). Rows stay byte-identical to the single-device engine.
         page_allocator: an existing PageAllocator to use instead of
         constructing one — `serving/replicas.py` shares a pool (and with it
-        the prefix-cache page references) across engine replicas.
-        compilation_cache_dir: enable jax's persistent compilation cache at
-        this directory before any engine phase is jitted (launch/
-        compile_cache.py) — repeated runs skip re-jit."""
-        if compilation_cache_dir is not None:
-            from repro.launch.compile_cache import enable_compilation_cache
-            enable_compilation_cache(compilation_cache_dir)
+        the prefix-cache page references) across engine replicas."""
         self.cfg = cfg
         self.mesh = mesh
         if mesh is not None:
-            # FSDP+TP parameter layout; a no-op when `params` already
+            # column-parallel serving layout; a no-op when `params` already
             # carries these shardings (replica groups pre-shard once)
-            params = jax.device_put(params, param_shardings(cfg, params, mesh))
-            self._constrain = make_constrain(mesh, slots)      # batched phases
-            self._constrain1 = make_constrain(mesh, 1)         # B=1 prefill
+            params = jax.device_put(
+                params, param_shardings(cfg, params, mesh, serving=True))
+            self._constrain = make_constrain(mesh, slots, serving=True)
+            self._constrain1 = make_constrain(mesh, 1, serving=True)  # B=1
         else:
             self._constrain = self._constrain1 = None
         self._cache_pspecs = self._pool_pspecs = None
@@ -301,14 +304,14 @@ class ServingEngine:
             if full:
                 new = self._with_specs(new, self._cache_pspecs)
             return logits, new
-        self._decode = jax.jit(_dec)
+        self._decode = _jit_phase(_dec)
         self._prefill_cache = {}
 
         def _vslab(params, toks, cache):
             logits, new, ckpts = verify_chunk(cfg, params, {"tokens": toks},
                                               cache, constrain=self._constrain)
             return logits, self._with_specs(new, self._cache_pspecs), ckpts
-        self._verify_slab = jax.jit(_vslab)
+        self._verify_slab = _jit_phase(_vslab)
         self._verify_fns: dict = {}
 
         if self.paged:
@@ -331,7 +334,7 @@ class ServingEngine:
             self.slot_pages: list = [[] for _ in range(slots)]
             self._pos_h = np.zeros((slots,), np.int64)   # host mirror of pos
             self._chunk_fns: dict = {}
-            self._paged_decode = jax.jit(self._make_paged_decode())
+            self._paged_decode = _jit_phase(self._make_paged_decode())
             self._cross_kv = None                         # encdec, computed once
 
         if mesh is not None:
@@ -388,7 +391,7 @@ class ServingEngine:
 
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill_cache:
-            self._prefill_cache[bucket] = jax.jit(
+            self._prefill_cache[bucket] = _jit_phase(
                 partial(prefill, self.cfg, max_len=self.max_len,
                         constrain=self._constrain1))
         return self._prefill_cache[bucket]
@@ -521,7 +524,7 @@ class ServingEngine:
                     pools = scatter_chunk_pages(pools, new, write_ids, b0, ps, nb)
                 return logits, new_state, self._with_specs(pools,
                                                            self._pool_pspecs)
-            self._chunk_fns[key] = jax.jit(fn)
+            self._chunk_fns[key] = _jit_phase(fn)
         return self._chunk_fns[key]
 
     def _ensure_pages(self, n: int, acquired: list) -> list:
@@ -843,7 +846,7 @@ class ServingEngine:
                                                      ps, nb)
                 return (logits, self._with_specs(new_state, self._cache_pspecs),
                         self._with_specs(pools, self._pool_pspecs), ckpts)
-            self._verify_fns[n_ctx] = (jax.jit(fn), nb)
+            self._verify_fns[n_ctx] = (_jit_phase(fn), nb)
         return self._verify_fns[n_ctx]
 
     def _spec_grow_pages(self, slot: int, upto: int) -> int:

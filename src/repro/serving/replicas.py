@@ -3,7 +3,7 @@
 
 Throughput past one engine comes from *replicas*: N `ServingEngine`s, each
 with its own slots, decode cache, and jitted phases (and, when `mesh=` is
-set, its own TP/FSDP-sharded execution), fed from a single shared queue.
+set, its own column-parallel sharded execution), fed from a single shared queue.
 `ReplicaGroup` is the engine-state split ROADMAP items 2 and 4 also need:
 
   per-replica — slots, decode cache, page *tables*, drafters, stats;
@@ -89,6 +89,15 @@ class ReplicaGroup:
         every `ServingEngine`."""
         if replicas < 1:
             raise ValueError(f"need at least one replica, got {replicas}")
+        n_dev = len(jax.devices())
+        if replicas > 1 and mesh is None and n_dev > 1:
+            # without a mesh every replica's params, cache and the shared
+            # pool land on devices[0]; the other devices would sit idle
+            raise ValueError(
+                f"{replicas} replicas with no mesh would all run on "
+                f"{jax.devices()[0]} while {n_dev} devices are visible; pass "
+                f"mesh= (placing each replica on its own device is not "
+                f"implemented)")
         self.replicas = replicas
         self.queue: deque = deque()
         self.queue_depth = queue_depth
@@ -112,7 +121,8 @@ class ReplicaGroup:
             # params is then a no-op instead of R host->device transfers
             from repro.distributed.sharding import param_shardings
             params = jax.device_put(params,
-                                    param_shardings(cfg, params, mesh))
+                                    param_shardings(cfg, params, mesh,
+                                                    serving=True))
         self.engines = [
             ServingEngine(
                 cfg, params, slots=slots, max_len=max_len, queue_depth=None,

@@ -121,11 +121,12 @@ class DraftModelDrafter:
         self.cfg = cfg
         if mesh is not None:
             # mesh-aware engines (DESIGN.md §15) shard the draft model with
-            # the same FSDP+TP rules as the target; the draft's slab cache
+            # the same serving layout as the target; the draft's slab cache
             # stays small enough to leave replicated
             from repro.distributed.sharding import param_shardings
             params = jax.device_put(params,
-                                    param_shardings(cfg, params, mesh))
+                                    param_shardings(cfg, params, mesh,
+                                                    serving=True))
         self.params = params
         self.slots = slots
         self.max_len = max_len

@@ -54,7 +54,9 @@ class Trainer:
     # ------------------------------------------------------------ state ---
 
     def init(self):
-        self.params = init_params(self.cfg, jax.random.PRNGKey(self.tcfg.seed))
+        # float32 master weights; the step casts to cfg.dtype for compute
+        self.params = init_params(self.cfg.replace(dtype="float32"),
+                                  jax.random.PRNGKey(self.tcfg.seed))
         self.opt_state = self._init_opt(self.params)
         self.step = 0
 

@@ -178,10 +178,9 @@ def test_reduced_dryrun_decode():
     assert out.count("DRYRUN-OK") == 3
 
 
-# ----------------------------------------------- shard_map compat wrapper --
-# The wrapper accepts the jax >= 0.5 spelling (axis_names=/check_vma=) and
-# translates to whichever implementation the installed jax provides. Both
-# dispatch paths run in-process (a 1x1 mesh needs no device forcing).
+# ------------------------------------------------------ shard_map wrapper --
+# The wrapper forwards check_vma and normalizes axis_names to a set; it runs
+# in-process (a 1x1 mesh needs no device forcing).
 
 
 def _wrapper_inputs():
@@ -195,8 +194,7 @@ def _wrapper_inputs():
 
 
 def test_shard_map_wrapper_new_spelling(monkeypatch):
-    """With jax.shard_map present (0.5.x), the wrapper forwards check_vma
-    and normalizes axis_names to a set."""
+    """The wrapper forwards check_vma and normalizes axis_names to a set."""
     import jax
     from repro.distributed.sharding import shard_map
 
@@ -212,31 +210,6 @@ def test_shard_map_wrapper_new_spelling(monkeypatch):
                    out_specs=out_s, axis_names=("data",), check_vma=False)
     assert seen == {"check_vma": False, "axis_names": {"data"}}
     assert float(fn(x)[3]) == 6.0          # wrapper returned the mapped fn
-
-
-def test_shard_map_wrapper_legacy_spelling(monkeypatch):
-    """Without jax.shard_map (0.4.x), the wrapper must reach
-    jax.experimental.shard_map with replication checking off (fully manual
-    mode) — and the mapped function must actually compute."""
-    import jax
-    import jax.experimental.shard_map as esm
-    import numpy as np
-    from repro.distributed.sharding import shard_map
-
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    real, seen = esm.shard_map, {}
-
-    def spy(f, *, mesh, in_specs, out_specs, **kw):
-        seen.update(kw)
-        return real(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    monkeypatch.setattr(esm, "shard_map", spy)
-    mesh, x, in_s, out_s = _wrapper_inputs()
-    fn = shard_map(lambda v: v + 1, mesh=mesh, in_specs=(in_s,),
-                   out_specs=out_s, check_vma=True)
-    assert seen == {"check_rep": False}
-    np.testing.assert_array_equal(np.asarray(jax.jit(fn)(x)),
-                                  np.asarray(x) + 1)
 
 
 # ------------------------------------------------------------- straggler ---

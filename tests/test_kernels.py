@@ -1,13 +1,16 @@
 """Per-kernel allclose vs. the pure-jnp oracles (interpret=True on CPU).
 
 Each Pallas kernel is swept over shapes (incl. non-aligned tails where the
-wrapper pads), GQA group factors, causal/non-causal, and dtypes.
+wrapper pads), GQA group factors, causal/non-causal, and dtypes. The
+retrieval ranking (`l2_rank`, plain XLA on every backend) is held to an
+exact numpy ranking, ties in index order.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.index.vector_index import l2_rank
 from repro.kernels import ref
 from repro.kernels.decode_attention import (decode_attention_pallas,
                                             paged_decode_attention_pallas,
@@ -17,7 +20,6 @@ from repro.kernels.decode_attention import (decode_attention_pallas,
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gating import moe_gating_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
-from repro.kernels.topk_l2 import topk_l2_pallas
 
 KEY = jax.random.PRNGKey(0)
 
@@ -130,23 +132,48 @@ def test_paged_verify_attention(B, H, Hkv, C, D, P, ps, nb, dtype):
 # --------------------------------------------------------------- topk_l2 ---
 
 
+def _numpy_ranking(db, q):
+    """Reference: float64 distances, stable sort (equal distances keep
+    index order)."""
+    d = np.sqrt(((np.asarray(q, np.float64)[:, None, :]
+                  - np.asarray(db, np.float64)[None]) ** 2).sum(-1))
+    idx = np.argsort(d, axis=1, kind="stable")
+    return np.take_along_axis(d, idx, axis=1), idx
+
+
+def _unit_rows(key, shape):
+    x = np.asarray(rand(key, shape))
+    return x / np.linalg.norm(x, axis=1, keepdims=True)
+
+
 @pytest.mark.parametrize("N,D,M,k", [
     (512, 64, 4, 5),
-    (1000, 128, 7, 10),   # non-aligned N -> wrapper pads
+    (1000, 128, 7, 10),   # non-aligned N -> rows pad to the next bucket
     (256, 32, 1, 1),
 ])
 def test_topk_l2(N, D, M, k):
     k1, k2 = jax.random.split(KEY)
-    db = rand(k1, (N, D))
-    q = rand(k2, (M, D))
-    d, i = topk_l2_pallas(db, q, k, bm=4, bn=128, interpret=True)
-    dr, ir = ref.topk_l2_ref(db, q, k)
-    np.testing.assert_allclose(np.asarray(d), np.asarray(dr), atol=1e-4, rtol=1e-4)
-    # indices may tie-break differently; distances must agree, and the
-    # returned indices must realize those distances
-    d2 = ((np.asarray(q)[:, None, :] - np.asarray(db)[None]) ** 2).sum(-1)
-    got = np.sqrt(np.take_along_axis(d2, np.asarray(i), axis=1))
-    np.testing.assert_allclose(got, np.asarray(dr), atol=1e-4, rtol=1e-4)
+    db, q = _unit_rows(k1, (N, D)), _unit_rows(k2, (M, D))
+    d, i = l2_rank(db, q, k)
+    dr, ir = _numpy_ranking(db, q)
+    assert d.shape == i.shape == (M, k)
+    np.testing.assert_array_equal(i, ir[:, :k])
+    np.testing.assert_allclose(d, dr[:, :k], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("k", [7, None])
+def test_topk_l2_tie_order(k):
+    """Duplicated rows tie exactly; both the top_k and the full-ranking
+    path must return them in index order, as numpy's stable sort does."""
+    k1, k2 = jax.random.split(KEY)
+    base = _unit_rows(k1, (75, 16))
+    db = np.concatenate([base, base[::-1], base, base[:37]])   # 262 rows
+    q = np.concatenate([base[[3, 40]], _unit_rows(k2, (2, 16))])
+    d, i = l2_rank(db, q, k)
+    dr, ir = _numpy_ranking(db, q)
+    kk = len(db) if k is None else k
+    np.testing.assert_array_equal(i, ir[:, :kk])
+    np.testing.assert_allclose(d, dr[:, :kk], atol=1e-5, rtol=1e-5)
 
 
 # -------------------------------------------------------------- ssm scan ---

@@ -30,6 +30,32 @@ def tiny():
     return cfg, params
 
 
+def test_init_params_in_deployment_dtype(tiny):
+    """A bf16 config gets bf16 leaves: the float32 draw rounded once, the
+    same values as casting the float32 params."""
+    cfg, params32 = tiny
+    params16 = init_params(cfg.replace(dtype="bfloat16"), jax.random.PRNGKey(0))
+    assert {str(a.dtype) for a in jax.tree.leaves(params16)} == {"bfloat16"}
+    for a, b in zip(jax.tree.leaves(params16), jax.tree.leaves(params32)):
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(b.astype(jnp.bfloat16)))
+
+
+def test_compilation_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache is one fixed directory in the checkout."""
+    from repro.launch import compile_cache
+    seen = []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: seen.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compilation_cache() == "/elsewhere/cache"
+    assert seen == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert compile_cache.enable_compilation_cache() == want
+    assert seen == [("jax_compilation_cache_dir", want)]
+
+
 # ------------------------------------------------------------- serving -----
 
 

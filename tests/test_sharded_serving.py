@@ -261,6 +261,34 @@ def test_replica_stats_live_dict_and_run_accounting(qwen):
     assert all(e.stats["runs"] == 0 for e in grp.engines)
 
 
+def test_replica_group_refuses_to_stack_replicas_on_one_device():
+    """With several devices visible and no mesh, replicas would all land on
+    devices[0]: the group must refuse instead of idling the other devices.
+    One replica, or a mesh, is still accepted."""
+    out = run_child("""
+    import jax
+    assert len(jax.devices()) == 2, jax.devices()
+    from repro.configs import get_smoke_config
+    from repro.data import lm_data
+    from repro.launch.mesh import make_serving_mesh
+    from repro.models import init_params
+    from repro.serving.replicas import ReplicaGroup
+    cfg = get_smoke_config("qwen2.5-3b").replace(vocab_size=lm_data.VOCAB)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    kw = dict(slots=2, max_len=64, page_size=8)
+    try:
+        ReplicaGroup(cfg, params, replicas=2, **kw)
+    except ValueError as e:
+        assert "2 devices are visible" in str(e), e
+        print("REFUSED")
+    ReplicaGroup(cfg, params, replicas=1, **kw)
+    ReplicaGroup(cfg, params, replicas=2,
+                 mesh=make_serving_mesh((1, 2)), **kw)
+    print("ACCEPTED")
+    """, devices=2)
+    assert "REFUSED" in out and "ACCEPTED" in out
+
+
 def test_aggregate_stats_sums_and_peaks():
     a = {"prefill_tokens": 3, "max_live": 2, "kv_bytes_peak": 100}
     b = {"prefill_tokens": 5, "max_live": 4, "kv_bytes_peak": 70, "extra": 1}
