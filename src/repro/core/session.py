@@ -379,6 +379,8 @@ class Session:
         # (share one registry across session/engine/frontend for a single
         # exposition surface — but one registry per engine)
         self.tracer = as_tracer(tracer)
+        if hasattr(retriever, "tracer"):    # its `retrieval.<call>` spans
+            retriever.tracer = self.tracer
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._m = {k: self.metrics.counter(f"session.{k}")
                    for k in ("queries", "queries_finished", "queries_failed",

@@ -11,11 +11,13 @@ Modes:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from repro.data.tokens import count_tokens
+from repro.obs import NULL_TRACER
 from .embedder import HashedEmbedder
 from .kmeans import kmeans
 from .segmenter import Segment, key_sentences, segment_document
@@ -36,6 +38,19 @@ def synth_evidence_texts(attr: str, description: str) -> list[str]:
     ]
 
 
+def _spanned(method):
+    """Run a public retrieval call inside a `retrieval.<method>` span of
+    the retriever's tracer (the session's, which `Session` hands it; it
+    reaches any `jax.profiler` trace even when it records nothing)."""
+    name = f"retrieval.{method.__name__}"
+
+    @functools.wraps(method)
+    def wrapper(self, *args, **kw):
+        with self.tracer.span(name, kind="retrieval", level=2):
+            return method(self, *args, **kw)
+    return wrapper
+
+
 @dataclass
 class _AttrState:
     evidence_texts: list = field(default_factory=list)
@@ -47,6 +62,8 @@ class _AttrState:
 
 
 class TwoLevelRetriever:
+    tracer = NULL_TRACER    # `Session` hands it its own; forks share it
+
     def __init__(self, corpus, embedder: HashedEmbedder | None = None, *,
                  mode: str = "quest", evidence_k: int = 3,
                  tau_init: float = 1.7, gamma_init: float = 1.25,
@@ -154,6 +171,7 @@ class TwoLevelRetriever:
 
     # --------------------------------------------------- document level ----
 
+    @_spanned
     def candidate_docs(self, table: str, attrs: list) -> list:
         """Distance-ranked candidates. Modes without a document-level filter
         still return a *ranked* list (they own the same embeddings; they just
@@ -175,11 +193,13 @@ class TwoLevelRetriever:
         ids, _ = self.doc_index.range_search(center, tau)
         return [d for d in ids if d in table_docs]
 
+    @_spanned
     def refine_candidates(self, table: str, attrs: list) -> list:
         return self.candidate_docs(table, attrs)
 
     # ----------------------------------------------------- evidence --------
 
+    @_spanned
     def add_evidence(self, table: str, attr: str, segments: list, doc_id=None):
         """`doc_id` records provenance: under a live corpus, evidence
         collected from a document that later mutates must be dropped
@@ -224,6 +244,7 @@ class TwoLevelRetriever:
             self._version += 1
         return dropped
 
+    @_spanned
     def finalize_thresholds(self, table: str, attrs: list, stats):
         """Automatic tau/gamma (paper §4.2 'Setting the Threshold')."""
         self._version += 1
@@ -339,6 +360,7 @@ class TwoLevelRetriever:
             hit.update(ids)
         return [segs[i] for i in sorted(hit)]
 
+    @_spanned
     def prefetch_segments(self, pairs) -> None:
         """Batched retrieval (DESIGN.md §9): fill the segment cache for many
         (doc_id, attr, table) pairs at once. All probes of all requested
@@ -374,18 +396,21 @@ class TwoLevelRetriever:
             for (attr, table, key), hit in zip(entries, hits):
                 self._seg_cache[key] = [segs[i] for i in sorted(hit)]
 
+    @_spanned
     def segments(self, doc_id, attr: str, table: str | None = None) -> list[str]:
         key = (doc_id, attr, table, self._version)
         if key not in self._seg_cache:
             self._seg_cache[key] = self._segments_for(doc_id, attr, table)
         return [s.text for s in self._seg_cache[key]]
 
+    @_spanned
     def segment_tokens(self, doc_id, attr: str, table: str | None = None) -> int:
         key = (doc_id, attr, table, self._version)
         if key not in self._seg_cache:
             self._seg_cache[key] = self._segments_for(doc_id, attr, table)
         return sum(s.tokens for s in self._seg_cache[key])
 
+    @_spanned
     def score_margin(self, doc_id, attr: str,
                      table: str | None = None):
         """Normalized retrieval confidence in [0, 1] for (doc, attr) —

@@ -204,6 +204,7 @@ def _copy_page_op(pool, src, dst):
 # Device-side page ops (jit-friendly; page ids arrive as traced int arrays).
 
 
+@jax.named_scope("kv_gather")
 def gather_page_views(pools: dict, table) -> dict:
     """Assemble contiguous per-row KV views through a page table.
 
@@ -219,6 +220,7 @@ def gather_page_views(pools: dict, table) -> dict:
     return out
 
 
+@jax.named_scope("kv_scatter")
 def scatter_token_pages(pools: dict, dense: dict, write_ids, block_starts,
                         page_size: int) -> dict:
     """Write back each row's active page after a decode step.
@@ -241,6 +243,7 @@ def scatter_token_pages(pools: dict, dense: dict, write_ids, block_starts,
     return out
 
 
+@jax.named_scope("kv_scatter")
 def scatter_chunk_pages_rows(pools: dict, view: dict, write_tables, block0s,
                              page_size: int, n_blocks: int) -> dict:
     """Per-row `scatter_chunk_pages` for batched speculative verification.
@@ -316,6 +319,7 @@ def release_trailing_pages(alloc, pages: list, keep_blocks: int) -> list:
     return pages[:keep_blocks]
 
 
+@jax.named_scope("kv_scatter")
 def scatter_chunk_pages(pools: dict, view: dict, write_ids, block0,
                         page_size: int, n_blocks: int) -> dict:
     """Write back the pages a B=1 prefill chunk dirtied.

@@ -119,6 +119,7 @@ def no_constrain(x, kind):
     return x
 
 
+@jax.named_scope("mlp")
 def mlp_apply(cfg: ModelConfig, p, x, constrain=no_constrain):
     act = activation_fn(cfg.activation)
     if cfg.gated_mlp:
@@ -399,29 +400,34 @@ def attn_chunk_apply(cfg: ModelConfig, p, x, *, start, k_cache, v_cache,
     B, C, _ = x.shape
     nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     q_pos = chunk_positions(start, B, C)                      # (B, C)
-    if cross:
-        q = proj("bsd,dhe->bshe", x, p["wq"])
-        if cfg.attn_bias:
-            q = q + p["bq"]
-    else:
-        q, k, v = _project_qkv(cfg, p, x, lora)
-        if cfg.use_rope:
-            q = apply_rope(q, q_pos, cfg.rope_theta)
-            k = apply_rope(k, q_pos, cfg.rope_theta)
-        k_cache = cache_write_chunk(k_cache, k, start)
-        v_cache = cache_write_chunk(v_cache, v, start)
-    qg = q.reshape(B, C, nkv, nq // nkv, hd)
-    s = jnp.einsum("bqhgd,bshd->bhgqs", qg, k_cache,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
-    kv_pos = jnp.arange(k_cache.shape[1])
-    ok = (kv_pos[None, None, :] <= q_pos[:, :, None]) if not cross else \
-        jnp.ones((B, C, k_cache.shape[1]), bool)
-    s = jnp.where(ok[:, None, None, :, :], s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqs,bshd->bqhgd", pr.astype(v_cache.dtype), v_cache)
-    out = out.reshape(B, C, nq, hd)
-    out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"), p["wo"]),
-                    "gather")
+    with jax.named_scope("qkv_proj"):
+        if cross:
+            q = proj("bsd,dhe->bshe", x, p["wq"])
+            if cfg.attn_bias:
+                q = q + p["bq"]
+        else:
+            q, k, v = _project_qkv(cfg, p, x, lora)
+            if cfg.use_rope:
+                q = apply_rope(q, q_pos, cfg.rope_theta)
+                k = apply_rope(k, q_pos, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        if not cross:
+            k_cache = cache_write_chunk(k_cache, k, start)
+            v_cache = cache_write_chunk(v_cache, v, start)
+        qg = q.reshape(B, C, nkv, nq // nkv, hd)
+        s = jnp.einsum("bqhgd,bshd->bhgqs", qg, k_cache,
+                       preferred_element_type=jnp.float32) * (hd ** -0.5)
+        kv_pos = jnp.arange(k_cache.shape[1])
+        ok = (kv_pos[None, None, :] <= q_pos[:, :, None]) if not cross else \
+            jnp.ones((B, C, k_cache.shape[1]), bool)
+        s = jnp.where(ok[:, None, None, :, :], s, -jnp.inf)
+        pr = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bhgqs,bshd->bqhgd", pr.astype(v_cache.dtype),
+                         v_cache)
+        out = out.reshape(B, C, nq, hd)
+    with jax.named_scope("out_proj"):
+        out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"),
+                             p["wo"]), "gather")
     if lora is not None:
         out = out + (out @ lora["a_o"]) @ lora["b_o"]
     return out, (k_cache, v_cache) if not cross else (None, None)
@@ -438,29 +444,35 @@ def attn_decode_apply(cfg: ModelConfig, p, x, *, pos, k_cache, v_cache, lora=Non
     """
     B, S, _ = x.shape
     nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    if cross:
-        q = proj("bsd,dhe->bshe", x, p["wq"])
-        if cfg.attn_bias:
-            q = q + p["bq"]
-        k_new = v_new = None
-        length = k_cache.shape[1] if cache_len is None else cache_len
-    else:
-        q, k, v = _project_qkv(cfg, p, x, lora)
-        if cfg.use_rope:
-            pp = jnp.broadcast_to(jnp.asarray(pos).reshape(-1, 1) if jnp.asarray(pos).ndim
-                                  else jnp.full((B, S), pos), (B, S))
-            q = apply_rope(q, pp, cfg.rope_theta)
-            k = apply_rope(k, pp, cfg.rope_theta)
-        k_new, v_new = k, v
-        k_cache = cache_write(k_cache, k, pos)
-        v_cache = cache_write(v_cache, v, pos)
-        length = pos + 1
-    qg = q.reshape(B, S, nkv, nq // nkv, hd)
-    impl = attn_impl or decode_attention
-    out = impl(qg, k_cache, v_cache, length)
-    out = out.reshape(B, S, nq, hd)
-    out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"), p["wo"]),
-                    "gather")
+    with jax.named_scope("qkv_proj"):
+        if cross:
+            q = proj("bsd,dhe->bshe", x, p["wq"])
+            if cfg.attn_bias:
+                q = q + p["bq"]
+        else:
+            q, k, v = _project_qkv(cfg, p, x, lora)
+            if cfg.use_rope:
+                pp = jnp.broadcast_to(
+                    jnp.asarray(pos).reshape(-1, 1) if jnp.asarray(pos).ndim
+                    else jnp.full((B, S), pos), (B, S))
+                q = apply_rope(q, pp, cfg.rope_theta)
+                k = apply_rope(k, pp, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        if cross:
+            k_new = v_new = None
+            length = k_cache.shape[1] if cache_len is None else cache_len
+        else:
+            k_new, v_new = k, v
+            k_cache = cache_write(k_cache, k, pos)
+            v_cache = cache_write(v_cache, v, pos)
+            length = pos + 1
+        qg = q.reshape(B, S, nkv, nq // nkv, hd)
+        impl = attn_impl or decode_attention
+        out = impl(qg, k_cache, v_cache, length)
+        out = out.reshape(B, S, nq, hd)
+    with jax.named_scope("out_proj"):
+        out = constrain(proj("bshe,hed->bsd", constrain(out, "gather"),
+                             p["wo"]), "gather")
     if lora is not None:
         out = out + (out @ lora["a_o"]) @ lora["b_o"]
     return out, (k_cache, v_cache) if not cross else (None, None)

@@ -32,6 +32,7 @@ def _cast(params, dtype):
     return jax.tree.map(c, params)
 
 
+@jax.named_scope("lm_head")
 def _logits(cfg, x, head):
     """LM head accumulated in float32 and returned in `cfg.logit_dtype`:
     greedy argmax over a bf16 vocabulary would tie (and flip between

@@ -62,6 +62,14 @@ ENGINE_STATS = {
     "decode_steps_saved": ("counter", "decode steps saved by acceptance"),
     "cancelled": ("counter", "requests cancelled"),
     "admission_deferred": ("counter", "admissions deferred on page pressure"),
+    "host_syncs": ("counter", "step-path host reads of a device value "
+                   "(not counted: blocking inside dispatch or input copies)"),
+    "sync_wait_s": ("counter", "host seconds in those reads"),
+    "step_s": ("counter", "host seconds inside step()"),
+    "host_gap_s": ("counter", "host seconds inside step() from a read to "
+                   "the next phase-program dispatch: no phase program queued"),
+    "first_tokens": ("counter", "requests whose first token reached the host"),
+    "ttft_s": ("counter", "summed seconds from submit to first token"),
 }
 
 FRONTEND_STATS = {

@@ -25,12 +25,19 @@ fit the stack: they are recorded as *async* spans via `begin()`/`end()`
 complete "X" events. `instant()` marks point events (prefix-cache hits,
 shed decisions, mutations).
 
-Levels gate cost: 0 = off, 1 = phases (query/round/run granularity),
-2 = full (per prefill chunk, decode step, verify round). `NULL_TRACER`
-is the shared no-op every layer defaults to, so tracing-off call sites
-pay one predicate per would-be span — the <5% overhead budget
-`benchmarks/bench_obs_overhead.py` gates (alongside byte-invariance of
-rows and ledger token columns, tracing on vs. off).
+Levels gate what the Python tracer records: 0 = off, 1 = phases
+(query/round/run granularity), 2 = full (per engine step, prefill chunk,
+decode step, verify round). `NULL_TRACER` is the shared default every
+layer falls back to; it records nothing.
+
+Every stack span, at every level and on `NULL_TRACER` too, also enters a
+`jax.profiler.TraceAnnotation` of its name (not its attrs), so any
+`jax.profiler` trace — `jax.profiler.trace(log_dir)` around a run, or the
+benchmark's `--trace 1` run — holds the program's phases on the same clock
+as the device's programs. With no profiler collecting, an annotation costs
+about a microsecond. Instants and async `begin()`/`end()` spans stay
+Python-only. JAX is imported at the first span, so `obs/` imports without
+it (and annotates nothing where it is missing).
 """
 from __future__ import annotations
 
@@ -94,23 +101,6 @@ class Span:
         return d
 
 
-class _SpanCtx:
-    """Context manager for one stack span; reused objects would race under
-    re-entrancy, so each `span()` call makes a fresh one (cheap: two
-    attributes)."""
-    __slots__ = ("_tracer", "_span")
-
-    def __init__(self, tracer: "Tracer", span: Span):
-        self._tracer = tracer
-        self._span = span
-
-    def __enter__(self) -> Span:
-        return self._span
-
-    def __exit__(self, *exc) -> None:
-        self._tracer._close(self._span)
-
-
 class _NullCtx:
     __slots__ = ()
 
@@ -122,6 +112,41 @@ class _NullCtx:
 
 
 _NULL_CTX = _NullCtx()
+_annotate = None        # jax.profiler.TraceAnnotation, resolved at first use
+
+
+def _annotation(name: str):
+    """A context manager that marks `name` in any `jax.profiler` trace
+    being collected (a no-op one where JAX is not installed)."""
+    global _annotate
+    if _annotate is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotate
+        except ImportError:
+            _annotate = lambda name: _NULL_CTX  # noqa: E731
+    return _annotate(name)
+
+
+class _SpanCtx:
+    """Context manager for one recorded stack span; reused objects would
+    race under re-entrancy, so each `span()` call makes a fresh one. The
+    profiler annotation is entered here, not in `span()`, so a span made
+    before its `with` marks only the body."""
+    __slots__ = ("_tracer", "_span", "_ann")
+
+    def __init__(self, tracer: "Tracer", span: Span):
+        self._tracer = tracer
+        self._span = span
+        self._ann = None
+
+    def __enter__(self) -> Span:
+        self._ann = _annotation(self._span.name)
+        self._ann.__enter__()
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        self._tracer._close(self._span)
 
 
 class Tracer:
@@ -130,7 +155,8 @@ class Tracer:
     clock: "wall" (perf_counter, relative to construction), "ticks"
     (fresh `TickClock`), or any zero-arg callable returning a number.
     level: 0/1/2 or "off"/"phases"/"full" — spans above the level are
-    dropped at the call site (`enabled()` / no-op context)."""
+    not recorded (`enabled()`), though their profiler annotation still
+    is."""
 
     def __init__(self, *, clock="wall", level=LEVEL_FULL):
         self.level = resolve_level(level)
@@ -172,7 +198,7 @@ class Tracer:
              level: int = LEVEL_PHASES, **attrs):
         """Open a nested stack span; use as a context manager."""
         if self.level < level:
-            return _NULL_CTX
+            return _annotation(name)
         span = self._new_span(name, kind, "X", attrs)
         self._stack.append(span)
         self.spans.append(span)
@@ -290,9 +316,10 @@ class Tracer:
 
 
 class NullTracer:
-    """Shared no-op tracer: default for every instrumented layer, so the
-    tracing-off path is one attribute load + one method call per span
-    site (gated <5% by bench_obs_overhead)."""
+    """Shared tracer that records nothing: default for every instrumented
+    layer. Its spans still mark any `jax.profiler` trace, so a trace
+    names the program's phases with no `Tracer` attached; the off path
+    costs one method call and one inactive annotation per span site."""
 
     level = LEVEL_OFF
     clock_kind = "off"
@@ -305,7 +332,7 @@ class NullTracer:
         return 0.0
 
     def span(self, name, **kw):
-        return _NULL_CTX
+        return _annotation(name)
 
     def instant(self, name, **kw) -> None:
         return None

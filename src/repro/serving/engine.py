@@ -137,8 +137,15 @@ class RunTruncated(RuntimeError):
 # precision carried through a fusion, whose extent differs from one program
 # shape to the next), so a mesh engine and a one-device engine decode the
 # same tokens.
-_jit_phase = partial(jax.jit,
-                     compiler_options={"xla_allow_excess_precision": False})
+_jit = partial(jax.jit, compiler_options={"xla_allow_excess_precision": False})
+
+
+def _jit_phase(fn, name: str):
+    """Jit one engine phase under a stable name: the compiled program is
+    `jit_<name>`, the name the device trace's XLA Modules line gives it
+    (PERF.md reads `jit_prefill_chunk` and `jit_verify_round`)."""
+    fn.__name__ = fn.__qualname__ = name
+    return _jit(fn)
 
 
 def _pow2_at_least(n: int) -> int:
@@ -293,6 +300,9 @@ class ServingEngine:
         self.cache["pos"] = jnp.zeros((slots,), jnp.int32)
         self._live = np.zeros((slots,), bool)
         self._tokens = jnp.zeros((slots, 1), jnp.int32)
+        # start of the open host gap (`_synced`): the device has no phase
+        # program queued from then until the next one is dispatched
+        self._gap_t0: Optional[float] = 0.0
 
         def _dec(params, tokens, cache):
             # full-batch decode gets the batched constrain hook + sticky
@@ -304,14 +314,14 @@ class ServingEngine:
             if full:
                 new = self._with_specs(new, self._cache_pspecs)
             return logits, new
-        self._decode = _jit_phase(_dec)
+        self._decode = _jit_phase(_dec, "slab_decode")
         self._prefill_cache = {}
 
         def _vslab(params, toks, cache):
             logits, new, ckpts = verify_chunk(cfg, params, {"tokens": toks},
                                               cache, constrain=self._constrain)
             return logits, self._with_specs(new, self._cache_pspecs), ckpts
-        self._verify_slab = _jit_phase(_vslab)
+        self._verify_slab = _jit_phase(_vslab, "slab_verify")
         self._verify_fns: dict = {}
 
         if self.paged:
@@ -334,7 +344,8 @@ class ServingEngine:
             self.slot_pages: list = [[] for _ in range(slots)]
             self._pos_h = np.zeros((slots,), np.int64)   # host mirror of pos
             self._chunk_fns: dict = {}
-            self._paged_decode = _jit_phase(self._make_paged_decode())
+            self._paged_decode = _jit_phase(self._make_paged_decode(),
+                                            "paged_decode")
             self._cross_kv = None                         # encdec, computed once
 
         if mesh is not None:
@@ -391,9 +402,12 @@ class ServingEngine:
 
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill_cache:
-            self._prefill_cache[bucket] = _jit_phase(
-                partial(prefill, self.cfg, max_len=self.max_len,
-                        constrain=self._constrain1))
+            cfg, max_len, constrain = self.cfg, self.max_len, self._constrain1
+
+            def fn(params, batch, length):
+                return prefill(cfg, params, batch, max_len=max_len,
+                               length=length, constrain=constrain)
+            self._prefill_cache[bucket] = _jit_phase(fn, "slab_prefill")
         return self._prefill_cache[bucket]
 
     def _prefill_sub(self, tokens: list):
@@ -415,8 +429,10 @@ class ServingEngine:
         self.stats["prefill_invocations"] += 1
         # attention-FLOPs proxy: KV positions computed against (S x S matrix)
         self.stats["prefill_ctx_positions"] += (self._extra + bucket) ** 2
-        return self._prefill_fn(bucket)(self.params, batch,
-                                        length=jnp.asarray(n, jnp.int32))
+        out = self._prefill_fn(bucket)(self.params, batch,
+                                       length=jnp.asarray(n, jnp.int32))
+        self._dispatched()
+        return out
 
     def _insert_slab_co(self, slot: int, req: Request):
         """Coroutine form of the slab-layout insert: yields between prefill
@@ -426,8 +442,9 @@ class ServingEngine:
         the old blocking `_insert_slab` did."""
         prompt = req.prompt
         sub, prefix_len, did_work = None, 0, False
-        if self.prefix_cache is not None:
-            entry = self.prefix_cache.match(prompt)
+        with self.tracer.span("engine.admit", kind="engine", level=2):
+            entry = None if self.prefix_cache is None else \
+                self.prefix_cache.match(prompt)
             if entry is not None and len(entry.tokens) >= self.prefix_min_len:
                 prefix_len = len(entry.tokens)
                 sub = expand_snapshot(entry.cache, self.max_len)
@@ -435,7 +452,7 @@ class ServingEngine:
                 self.stats["prefix_saved_tokens"] += prefix_len
                 self.tracer.instant("engine.prefix_hit", kind="engine",
                                     level=2, saved=prefix_len)
-            else:
+            elif self.prefix_cache is not None:
                 # first request of a prefix group: prefill the shared prefix
                 # exactly (state-correct snapshot boundary), then continue
                 boundary = min(int(req.shared_len), len(prompt) - 1)
@@ -460,6 +477,7 @@ class ServingEngine:
                 did_work = True
                 logits, sub = self._decode(self.params,
                                            jnp.asarray([[t]], jnp.int32), sub)
+                self._dispatched()
                 self.stats["prefill_invocations"] += 1
                 # each token-step attends the full max_len KV buffer
                 self.stats["prefill_ctx_positions"] += self.max_len
@@ -524,7 +542,7 @@ class ServingEngine:
                     pools = scatter_chunk_pages(pools, new, write_ids, b0, ps, nb)
                 return logits, new_state, self._with_specs(pools,
                                                            self._pool_pspecs)
-            self._chunk_fns[key] = _jit_phase(fn)
+            self._chunk_fns[key] = _jit_phase(fn, "prefill_chunk")
         return self._chunk_fns[key]
 
     def _ensure_pages(self, n: int, acquired: list) -> list:
@@ -578,35 +596,36 @@ class ServingEngine:
                 yield               # cooperative point between chunks
             first = False
             true_clen = min(cs, n - i)
-            with_images = self._extra > 0 and lpos == 0
-            extra = self._extra if with_images else 0
-            llen_pad = cs + extra         # positions the padded chunk touches
-            if has_pool:
-                nb = (llen_pad + ps - 2) // ps + 1 if ps > 1 else llen_pad
-                need = -(-(lpos + llen_pad) // ps)
-                n_ctx = _pow2_at_least(max(need, nb))
-                b0 = min(lpos // ps, n_ctx - nb)
-                ctx = [pages[b] if b < len(pages) else PAGE_SINK
-                       for b in range(n_ctx)]
-                wids = [pages[b] if b < len(pages) else PAGE_SINK
-                        for b in range(b0, b0 + nb)]
-            else:
-                nb = n_ctx = b0 = 0
-                ctx, wids = [], []
-            chunk = list(tokens[i:i + true_clen]) + [0] * (cs - true_clen)
-            fn = self._chunk_fn(n_ctx, nb, with_images)
-            logits, state, self.alloc.pools = fn(
-                self.params, state, self.alloc.pools,
-                jnp.asarray(ctx, jnp.int32),
-                jnp.asarray(chunk, jnp.int32)[None, :],
-                jnp.asarray(true_clen, jnp.int32),
-                jnp.asarray(wids, jnp.int32), jnp.asarray(b0, jnp.int32))
+            with self.tracer.span("engine.prefill_chunk", kind="engine",
+                                  level=2, tokens=true_clen):
+                with_images = self._extra > 0 and lpos == 0
+                extra = self._extra if with_images else 0
+                llen_pad = cs + extra     # positions the padded chunk touches
+                if has_pool:
+                    nb = (llen_pad + ps - 2) // ps + 1 if ps > 1 else llen_pad
+                    need = -(-(lpos + llen_pad) // ps)
+                    n_ctx = _pow2_at_least(max(need, nb))
+                    b0 = min(lpos // ps, n_ctx - nb)
+                    ctx = [pages[b] if b < len(pages) else PAGE_SINK
+                           for b in range(n_ctx)]
+                    wids = [pages[b] if b < len(pages) else PAGE_SINK
+                            for b in range(b0, b0 + nb)]
+                else:
+                    nb = n_ctx = b0 = 0
+                    ctx, wids = [], []
+                chunk = list(tokens[i:i + true_clen]) + [0] * (cs - true_clen)
+                fn = self._chunk_fn(n_ctx, nb, with_images)
+                logits, state, self.alloc.pools = fn(
+                    self.params, state, self.alloc.pools,
+                    jnp.asarray(ctx, jnp.int32),
+                    jnp.asarray(chunk, jnp.int32)[None, :],
+                    jnp.asarray(true_clen, jnp.int32),
+                    jnp.asarray(wids, jnp.int32), jnp.asarray(b0, jnp.int32))
+                self._dispatched()
             self.stats["prefill_invocations"] += 1
             self.stats["prefill_chunks"] += 1
             self.stats["prefill_ctx_positions"] += \
                 llen_pad * (n_ctx * ps if has_pool else llen_pad)
-            self.tracer.instant("engine.prefill_chunk", kind="engine",
-                                level=2, tokens=int(true_clen))
             i += true_clen
             lpos += true_clen + extra
         return logits, state, lpos, first
@@ -672,9 +691,10 @@ class ServingEngine:
         blocks = -(-cap // ps) if self.alloc.pools else 0
         acquired: list = []
         state, prefix_len, pages = None, 0, []
-        try:
-            if self.prefix_cache is not None:
-                entry = self.prefix_cache.match(prompt)
+        with self.tracer.span("engine.admit", kind="engine", level=2):
+            try:
+                entry = None if self.prefix_cache is None else \
+                    self.prefix_cache.match(prompt)
                 if entry is not None and len(entry.tokens) >= self.prefix_min_len:
                     # O(1) splice: share the full pages, CoW the boundary page
                     prefix_len = len(entry.tokens)
@@ -694,15 +714,18 @@ class ServingEngine:
                     self.stats["prefix_saved_tokens"] += prefix_len
                     self.tracer.instant("engine.prefix_hit", kind="engine",
                                         level=2, saved=prefix_len)
-            if blocks > len(pages):
-                pages = pages + self._ensure_pages(blocks - len(pages), acquired)
-        except PagePoolExhausted:
-            if acquired:                    # roll back the splice/CoW refs
-                self.alloc.release(acquired)
-            raise
-        self.slot_pages[slot] = pages
-        if state is None:
-            state = self._init_state_sub()
+                if blocks > len(pages):
+                    pages = pages + self._ensure_pages(blocks - len(pages),
+                                                       acquired)
+            except PagePoolExhausted:
+                if acquired:                # roll back the splice/CoW refs
+                    self.alloc.release(acquired)
+                raise
+            self.slot_pages[slot] = pages
+            hit = state is not None
+            if not hit:
+                state = self._init_state_sub()
+        if not hit:
             boundary = 0 if self.prefix_cache is None else \
                 min(int(req.shared_len), plen - 1)
             if boundary >= self.prefix_min_len:
@@ -756,14 +779,40 @@ class ServingEngine:
             f"exceeds cache max_len={self.max_len}")
         co = (self._insert_paged_co if self.paged else self._insert_slab_co)
         logits = yield from co(slot, req)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        self._tokens = self._tokens.at[slot, 0].set(nxt)
-        req.out.append(nxt)
-        self.active[slot] = req
-        self._live[slot] = True
-        if self.spec:
-            self.drafter.on_insert(slot, req)
-        self._note_kv_bytes()
+        with self.tracer.span("engine.first_token", kind="engine", level=2):
+            t0 = time.perf_counter()
+            nxt = int(jnp.argmax(logits[0, -1]))
+            self._synced(t0)
+            if not req.retries:         # a requeued request counted already
+                self.stats["first_tokens"] += 1
+                self.stats["ttft_s"] += time.time() - req.submitted_s
+            self._tokens = self._tokens.at[slot, 0].set(nxt)
+            req.out.append(nxt)
+            self.active[slot] = req
+            self._live[slot] = True
+            if self.spec:
+                self.drafter.on_insert(slot, req)
+            self._note_kv_bytes()
+
+    def _synced(self, t0: float, reads: int = 1) -> None:
+        """Count `reads` host reads of device values, begun at `t0`
+        (perf_counter) and just returned. Each blocks the host until the
+        device has produced the value, which leaves the device with no
+        phase program queued: a host gap opens, which the next phase
+        dispatch closes (`_dispatched`)."""
+        now = time.perf_counter()
+        self.stats["host_syncs"] += reads
+        self.stats["sync_wait_s"] += now - t0
+        if self._gap_t0 is not None:    # the wait itself is not host work
+            self.stats["host_gap_s"] += t0 - self._gap_t0
+        self._gap_t0 = now
+
+    def _dispatched(self) -> None:
+        """A phase program was just dispatched: the host gap since the last
+        read (its Python, input copies and dispatch) ends here."""
+        if self._gap_t0 is not None:
+            self.stats["host_gap_s"] += time.perf_counter() - self._gap_t0
+            self._gap_t0 = None
 
     def _insert(self, slot: int, req: Request):
         """Blocking insert (legacy API, kept for tests/direct callers):
@@ -808,14 +857,18 @@ class ServingEngine:
             self._pos_h += 1
         else:
             logits, self.cache = self._decode(self.params, self._tokens, self.cache)
+        self._dispatched()
         self.stats["decode_steps"] += 1
         self.stats["decode_slot_steps"] += len(self.active)
         self.stats["max_live"] = max(self.stats["max_live"], len(self.active))
+        t0 = time.perf_counter()
         nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+        pos = np.asarray(self.cache["pos"])
+        self._synced(t0, 2)
         for slot, req in list(self.active.items()):
             tok = int(nxt[slot])
             req.out.append(tok)
-            full = int(np.asarray(self.cache["pos"])[slot]) >= self.max_len - 1
+            full = int(pos[slot]) >= self.max_len - 1
             if tok == req.eos_id or len(req.out) >= req.max_new or full:
                 self._finish(slot, req)
         self._tokens = jnp.asarray(nxt[:, None], jnp.int32)
@@ -846,7 +899,7 @@ class ServingEngine:
                                                      ps, nb)
                 return (logits, self._with_specs(new_state, self._cache_pspecs),
                         self._with_specs(pools, self._pool_pspecs), ckpts)
-            self._verify_fns[n_ctx] = (_jit_phase(fn), nb)
+            self._verify_fns[n_ctx] = (_jit_phase(fn, "verify_round"), nb)
         return self._verify_fns[n_ctx]
 
     def _spec_grow_pages(self, slot: int, upto: int) -> int:
@@ -895,10 +948,47 @@ class ServingEngine:
         suffixes back — position truncation + page scrub/ref-release for
         attention KV, per-position state checkpoints for SSM/conv state —
         so the engine state is exactly what plain decode would have built."""
-        C = self.spec_k + 1
+        with self.tracer.span("engine.draft", kind="engine", level=2):
+            live, pos_h, drafts = self._spec_drafts()
+            if live:
+                toks = np.zeros((self.slots, self.spec_k + 1), np.int64)
+                for s in live:
+                    row = [self.active[s].out[-1]] + drafts[s]
+                    toks[s, :len(row)] = row
+                if self.paged:
+                    fn, ctx, wtabs, b0s = self._verify_inputs(live, pos_h)
+        if not live:
+            return                       # all slots drained; run() reinserts
+        if self.paged:
+            logits, new_state, self.alloc.pools, ckpts = fn(
+                self.params, self.cache, self.alloc.pools, ctx,
+                jnp.asarray(toks, jnp.int32), wtabs, b0s)
+            cache = dict(self.cache)
+            cache.update(new_state)
+        else:
+            logits, cache, ckpts = self._verify_slab(
+                self.params, jnp.asarray(toks, jnp.int32), self.cache)
+            cache = dict(cache)
+        self._dispatched()
+
+        self.stats["decode_steps"] += 1
+        self.stats["spec_rounds"] += 1
+        self.stats["decode_slot_steps"] += len(live)
+        self.stats["max_live"] = max(self.stats["max_live"], len(live))
+        with self.tracer.span("engine.accept", kind="engine", level=2):
+            self._spec_accept(live, pos_h, drafts, logits, cache, ckpts)
+
+    def _spec_drafts(self):
+        """The draft half of a verify round: each live slot's drafts,
+        clamped to what its page table can grow to. Returns (live slots,
+        host positions, drafts by slot)."""
         live = [s for s in range(self.slots) if self._live[s]]
-        pos_h = (self._pos_h.astype(np.int64).copy() if self.paged else
-                 np.asarray(self.cache["pos"]).astype(np.int64).copy())
+        if self.paged:
+            pos_h = self._pos_h.astype(np.int64).copy()
+        else:
+            t0 = time.perf_counter()
+            pos_h = np.asarray(self.cache["pos"]).astype(np.int64).copy()
+            self._synced(t0)
         reqs = {s: self.active[s] for s in live}
         k_eff = {}
         for s in live:
@@ -911,56 +1001,43 @@ class ServingEngine:
             drafts[s] = list(drafts.get(s) or [])[: k_eff[s]]
         if self.paged:
             live = self._spec_clamp_drafts(live, pos_h, drafts)
-            if not live:
-                return                   # all slots drained; run() reinserts
-        toks = np.zeros((self.slots, C), np.int64)
-        true_c = {}
+        return live, pos_h, drafts
+
+    def _verify_inputs(self, live, pos_h):
+        """The paged verify program for this round's context width and its
+        page-table inputs on the device: (fn, context table, write tables,
+        block starts)."""
+        C, ps = self.spec_k + 1, self.page_size
+        nb_probe = (C + ps - 2) // ps + 1 if ps > 1 else C
+        need_ctx = 1
         for s in live:
-            row = [self.active[s].out[-1]] + drafts[s]
-            true_c[s] = len(row)
-            toks[s, :len(row)] = row
+            p0 = int(pos_h[s])
+            need_ctx = max(need_ctx, -(-(p0 + C) // ps), p0 // ps + nb_probe)
+        n_ctx = _pow2_at_least(need_ctx)
+        fn, nb = self._verify_fn(n_ctx)
+        ctx = np.full((self.slots, n_ctx), PAGE_SINK, np.int32)
+        wtabs = np.full((self.slots, nb), PAGE_SINK, np.int32)
+        b0s = np.zeros((self.slots,), np.int32)
+        for s in live:
+            pages = self.slot_pages[s]
+            ctx[s, :min(len(pages), n_ctx)] = pages[:n_ctx]
+            b0 = min(int(pos_h[s]) // ps, n_ctx - nb)
+            b0s[s] = b0
+            for j in range(nb):
+                b = b0 + j
+                if b < len(pages):
+                    wtabs[s, j] = pages[b]
+        return fn, jnp.asarray(ctx), jnp.asarray(wtabs), jnp.asarray(b0s)
 
-        if self.paged:
-            ps = self.page_size
-            nb_probe = (C + ps - 2) // ps + 1 if ps > 1 else C
-            need_ctx = 1
-            for s in live:
-                p0 = int(pos_h[s])
-                need_ctx = max(need_ctx, -(-(p0 + C) // ps),
-                               p0 // ps + nb_probe)
-            n_ctx = _pow2_at_least(need_ctx)
-            fn, nb = self._verify_fn(n_ctx)
-            ctx = np.full((self.slots, n_ctx), PAGE_SINK, np.int32)
-            wtabs = np.full((self.slots, nb), PAGE_SINK, np.int32)
-            b0s = np.zeros((self.slots,), np.int32)
-            for s in live:
-                pages = self.slot_pages[s]
-                ctx[s, :min(len(pages), n_ctx)] = pages[:n_ctx]
-                b0 = min(int(pos_h[s]) // ps, n_ctx - nb)
-                b0s[s] = b0
-                for j in range(nb):
-                    b = b0 + j
-                    if b < len(pages):
-                        wtabs[s, j] = pages[b]
-            logits, new_state, self.alloc.pools, ckpts = fn(
-                self.params, self.cache, self.alloc.pools,
-                jnp.asarray(ctx), jnp.asarray(toks, jnp.int32),
-                jnp.asarray(wtabs), jnp.asarray(b0s))
-            cache = dict(self.cache)
-            cache.update(new_state)
-        else:
-            logits, cache, ckpts = self._verify_slab(
-                self.params, jnp.asarray(toks, jnp.int32), self.cache)
-            cache = dict(cache)
-
-        self.stats["decode_steps"] += 1
-        self.stats["spec_rounds"] += 1
-        self.stats["decode_slot_steps"] += len(live)
-        self.stats["max_live"] = max(self.stats["max_live"], len(live))
-
+    def _spec_accept(self, live, pos_h, drafts, logits, cache, ckpts):
+        """The accept half of a verify round: read the target's greedy
+        tokens back, emit each slot's agreeing prefix plus one, and roll
+        the rejected suffixes back."""
+        t0 = time.perf_counter()
         Y = np.asarray(jnp.argmax(logits, axis=-1))          # (slots, C)
-        new_pos = pos_h.copy()
         nxt = np.asarray(self._tokens[:, 0]).copy()
+        self._synced(t0, 2)
+        new_pos = pos_h.copy()
         keeps = np.ones((self.slots,), np.int32)
         restore = np.zeros((self.slots,), bool)
         for s in live:
@@ -996,7 +1073,8 @@ class ServingEngine:
                 if self.paged and self.alloc.pools:
                     # page-truncate + ref-release the rejected suffix
                     pages = self.slot_pages[s]
-                    end = min(p0 + true_c[s], len(pages) * self.page_size)
+                    end = min(p0 + 1 + len(d),
+                              len(pages) * self.page_size)
                     if p0 + keep < end:
                         self.alloc.pools = truncate_pages(
                             self.alloc.pools, pages, p0 + keep, end,
@@ -1158,7 +1236,30 @@ class ServingEngine:
         stats['admission_deferred'] counts the stall. The exception still
         raises when nothing is live or inserting, i.e. waiting could never
         free a page (and always with the default defer_admission=False)."""
-        budget = max_prefill_chunks
+        t0 = time.perf_counter()
+        if self._gap_t0 is not None:    # count the engine's own gap only
+            self._gap_t0 = t0
+        try:
+            with self.tracer.span("engine.step", kind="engine", level=2):
+                self._admit(max_prefill_chunks, defer_admission)
+                if self.active:
+                    name = "engine.verify_round" if self.spec else \
+                        "engine.decode_step"
+                    with self.tracer.span(name, kind="engine", level=2,
+                                          live=len(self.active)):
+                        self._spec_step() if self.spec else self._step()
+        finally:
+            t1 = time.perf_counter()
+            self.stats["step_s"] += t1 - t0
+            if self._gap_t0 is not None:
+                self.stats["host_gap_s"] += t1 - self._gap_t0
+                self._gap_t0 = t1
+        return bool(self.queue or self.active or self._inserting)
+
+    def _admit(self, budget: Optional[int], defer_admission: bool) -> None:
+        """The admission half of `step()`: resume in-flight inserts, then
+        start queued requests in free slots, within `budget` prefill
+        units."""
         for slot in sorted(self._inserting):
             if budget is not None and budget <= 0:
                 break
@@ -1189,16 +1290,6 @@ class ServingEngine:
                                         kind="engine", level=2, rid=req.rid)
                     break
                 raise
-        if self.active:
-            if self.tracer.enabled(2):
-                name = "engine.verify_round" if self.spec else \
-                    "engine.decode_step"
-                with self.tracer.span(name, kind="engine", level=2,
-                                      live=len(self.active)):
-                    self._spec_step() if self.spec else self._step()
-            else:
-                self._spec_step() if self.spec else self._step()
-        return bool(self.queue or self.active or self._inserting)
 
     def run(self, max_steps: int = 10_000, *, strict: bool = True):
         """Drain the queue. If `max_steps` is exhausted with requests still
