@@ -220,10 +220,35 @@ def gather_page_views(pools: dict, table) -> dict:
     return out
 
 
+def write_pages(pool, view, rows, starts, ids):
+    """Copy page j of `view`, view[:, rows[j], starts[j]:starts[j] +
+    page_size], into pool page ids[j], for each j; where ids repeat (only
+    PAGE_SINK does, and it is never read) the later page wins.
+
+    pool: (Lax, num_pages, page_size, *tail); view: (Lax, B, S, *tail);
+    rows: host ints; starts, ids: int32 arrays as long as rows. Each page is
+    one dynamic slice and one dynamic-update-slice, so the pool is updated
+    in place where the caller donates it. A page-axis scatter with a vector
+    of ids (`pool.at[:, ids].set(pages)`) lowers on TPU to a relayout of the
+    whole pool out and back around it, and cutting the pages out by a vmap
+    over rows transposes the whole view.
+    """
+    zero = jnp.zeros((), jnp.int32)
+    tail = (zero,) * (pool.ndim - 3)
+    size = (pool.shape[0], 1) + pool.shape[2:]
+    for j, b in enumerate(rows):
+        page = jax.lax.dynamic_slice(view, (zero, jnp.int32(b), starts[j]) + tail,
+                                     size)
+        pool = jax.lax.dynamic_update_slice(pool, page.astype(pool.dtype),
+                                            (zero, ids[j], zero) + tail)
+    return pool
+
+
 @jax.named_scope("kv_scatter")
 def scatter_token_pages(pools: dict, dense: dict, write_ids, block_starts,
                         page_size: int) -> dict:
-    """Write back each row's active page after a decode step.
+    """Write back each row's active page after a decode step, in place
+    (`write_pages`; the caller donates `pools`).
 
     dense: per-key (Lax, B, S, *tail) views returned by the model; the only
     page a decode step dirties for row b is the one holding `pos`, whose
@@ -231,22 +256,18 @@ def scatter_token_pages(pools: dict, dense: dict, write_ids, block_starts,
     (PAGE_SINK for dead rows). Returns updated pools.
     """
     starts = jnp.asarray(block_starts, jnp.int32)
-    out = dict(pools)
-    for k, pool in pools.items():
-        view = dense[k]
-
-        def one_row(row, s):                     # (Lax, S, *tail) -> page
-            return jax.lax.dynamic_slice_in_dim(row, s, page_size, axis=1)
-        pages = jax.vmap(one_row, in_axes=(1, 0), out_axes=1)(view, starts)
-        out[k] = pool.at[:, jnp.asarray(write_ids, jnp.int32)].set(
-            pages.astype(pool.dtype))
-    return out
+    ids = jnp.asarray(write_ids, jnp.int32)
+    rows = range(ids.shape[0])
+    return {k: write_pages(pool, dense[k], rows, starts, ids)
+            for k, pool in pools.items()}
 
 
 @jax.named_scope("kv_scatter")
 def scatter_chunk_pages_rows(pools: dict, view: dict, write_tables, block0s,
                              page_size: int, n_blocks: int) -> dict:
-    """Per-row `scatter_chunk_pages` for batched speculative verification.
+    """Per-row `scatter_chunk_pages` for batched speculative verification:
+    B x n_blocks pages written in place (`write_pages`; the caller donates
+    `pools`).
 
     view: per-key (Lax, B, nb_ctx*ps, *tail) gathered contexts the verify
     chunk was computed over; row b dirtied blocks [block0s[b], block0s[b] +
@@ -255,18 +276,17 @@ def scatter_chunk_pages_rows(pools: dict, view: dict, write_tables, block0s,
     share writable pages (the engine CoWs shared boundary pages at insert),
     so duplicate sink ids are the only collisions and the sink is never read.
     """
-    b0 = jnp.asarray(block0s, jnp.int32)
     ids = jnp.asarray(write_tables, jnp.int32)               # (B, nb)
+    rows = [b for b in range(ids.shape[0]) for _ in range(n_blocks)]
     out = dict(pools)
     for k, pool in pools.items():
         v = view[k]
-        blocked = v.reshape((v.shape[0], v.shape[1], -1, page_size) + v.shape[3:])
-
-        def one_row(row, s):                     # (Lax, nb_ctx, ps, *tail)
-            return jax.lax.dynamic_slice_in_dim(row, s, n_blocks, axis=1)
-        pages = jax.vmap(one_row, in_axes=(1, 0), out_axes=1)(blocked, b0)
-        flat = pages.reshape((pages.shape[0], -1) + pages.shape[3:])
-        out[k] = pool.at[:, ids.reshape(-1)].set(flat.astype(pool.dtype))
+        # blocks [b0, b0 + n_blocks) of each row, b0 clamped into the view
+        b0 = jnp.clip(jnp.asarray(block0s, jnp.int32), 0,
+                      v.shape[2] // page_size - n_blocks)
+        blocks = b0[:, None] + jnp.arange(n_blocks, dtype=jnp.int32)
+        out[k] = write_pages(pool, v, rows, (blocks * page_size).reshape(-1),
+                             ids.reshape(-1))
     return out
 
 
@@ -319,22 +339,16 @@ def release_trailing_pages(alloc, pages: list, keep_blocks: int) -> list:
     return pages[:keep_blocks]
 
 
-@jax.named_scope("kv_scatter")
 def scatter_chunk_pages(pools: dict, view: dict, write_ids, block0,
                         page_size: int, n_blocks: int) -> dict:
-    """Write back the pages a B=1 prefill chunk dirtied.
+    """Write back the pages a B=1 prefill chunk dirtied, in place
+    (`write_pages`; the caller donates `pools`).
 
     view: per-key (Lax, 1, nb_ctx*ps, *tail) gathered context the chunk was
     computed over (chunk K/V written in place); blocks [block0, block0 +
     n_blocks) cover the chunk (plus CoW slack), write_ids (n_blocks,) their
     physical pages (padded with PAGE_SINK past the allocation).
     """
-    b0 = jnp.asarray(block0, jnp.int32)
-    out = dict(pools)
-    for k, pool in pools.items():
-        v = view[k]
-        blocked = v.reshape((v.shape[0], -1, page_size) + v.shape[3:])
-        pages = jax.lax.dynamic_slice_in_dim(blocked, b0, n_blocks, axis=1)
-        out[k] = pool.at[:, jnp.asarray(write_ids, jnp.int32)].set(
-            pages.astype(pool.dtype))
-    return out
+    return scatter_chunk_pages_rows(
+        pools, view, jnp.asarray(write_ids, jnp.int32)[None],
+        jnp.asarray(block0, jnp.int32)[None], page_size, n_blocks)

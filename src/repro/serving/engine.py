@@ -140,12 +140,19 @@ class RunTruncated(RuntimeError):
 _jit = partial(jax.jit, compiler_options={"xla_allow_excess_precision": False})
 
 
-def _jit_phase(fn, name: str):
+def _jit_phase(fn, name: str, donate: tuple = ()):
     """Jit one engine phase under a stable name: the compiled program is
     `jit_<name>`, the name the device trace's XLA Modules line gives it
-    (PERF.md reads `jit_prefill_chunk` and `jit_verify_round`)."""
+    (PERF.md reads `jit_prefill_chunk` and `jit_verify_round`).
+
+    donate: argument positions whose buffers the program may reuse. The
+    paged phases donate the KV pool, so the pages they dirty are written in
+    place instead of into a fresh copy of the whole pool; every caller
+    rebinds `alloc.pools` from the result. The state cache is never
+    donated: the prefix cache keeps a B=1 prefill state by reference, and a
+    verify round's rollback reads the cache it was given."""
     fn.__name__ = fn.__qualname__ = name
-    return _jit(fn)
+    return _jit(fn, donate_argnums=donate)
 
 
 def _pow2_at_least(n: int) -> int:
@@ -345,7 +352,7 @@ class ServingEngine:
             self._pos_h = np.zeros((slots,), np.int64)   # host mirror of pos
             self._chunk_fns: dict = {}
             self._paged_decode = _jit_phase(self._make_paged_decode(),
-                                            "paged_decode")
+                                            "paged_decode", donate=(3,))
             self._cross_kv = None                         # encdec, computed once
 
         if mesh is not None:
@@ -542,7 +549,8 @@ class ServingEngine:
                     pools = scatter_chunk_pages(pools, new, write_ids, b0, ps, nb)
                 return logits, new_state, self._with_specs(pools,
                                                            self._pool_pspecs)
-            self._chunk_fns[key] = _jit_phase(fn, "prefill_chunk")
+            self._chunk_fns[key] = _jit_phase(fn, "prefill_chunk",
+                                              donate=(2,))
         return self._chunk_fns[key]
 
     def _ensure_pages(self, n: int, acquired: list) -> list:
@@ -899,7 +907,8 @@ class ServingEngine:
                                                      ps, nb)
                 return (logits, self._with_specs(new_state, self._cache_pspecs),
                         self._with_specs(pools, self._pool_pspecs), ckpts)
-            self._verify_fns[n_ctx] = (_jit_phase(fn, "verify_round"), nb)
+            self._verify_fns[n_ctx] = (
+                _jit_phase(fn, "verify_round", donate=(2,)), nb)
         return self._verify_fns[n_ctx]
 
     def _spec_grow_pages(self, slot: int, upto: int) -> int:

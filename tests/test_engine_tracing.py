@@ -69,8 +69,8 @@ def test_phase_programs_carry_stable_names(model, monkeypatch):
     seen = {}
     real = engine_mod._jit
 
-    def recording(fn):
-        jitted = real(fn)
+    def recording(fn, **opts):
+        jitted = real(fn, **opts)
 
         def call(*args, **kw):
             seen.setdefault(fn.__name__, (jitted, args, kw))
