@@ -6,7 +6,8 @@ It imports nothing of the program. The weights are the benchmark's own:
 `make_weights` draws them from the run's seed on the device, in one jitted
 call, in the dtype they are served in, laid out as the program's parameter
 tree (a test holds the layout to `jax.eval_shape` of the program's
-`init_params`). The reference reads those values and computes in
+`init_params`), on one device or, given their shardings, over a serving
+mesh. The reference reads those values and computes in
 float32 with every matrix product at `Precision.HIGHEST`, one layer at a
 time, so that it fits beside them on one chip.
 
@@ -82,8 +83,15 @@ def seed_key(seed: int):
                               (seed >> 31) & 0x7FFFFFFF)
 
 
-def make_weights(conf: dict, seed: int) -> dict:
-    return jax.jit(lambda k: _draw(conf, k))(seed_key(seed))
+def make_weights(conf: dict, seed: int, shardings=None) -> dict:
+    """The weights of `seed`. With `shardings`, a tree of shardings that
+    mirrors the weights (a serving mesh's layout), each leaf is drawn
+    straight into its sharding, so that no device ever holds the whole
+    tree; the values are those of the draw without it (random bits do not
+    depend on the layout)."""
+    kw = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(lambda k: _draw(conf, k), **kw)(seed_key(seed))
+
 
 
 # ------------------------------------------------------ lower precision ---

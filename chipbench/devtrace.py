@@ -9,10 +9,13 @@ its own calls into each layer there with `jax.profiler.TraceAnnotation`
 (names starting `chipbench.`). Busy time is the union of the program
 executions of a chip, averaged over the chips used; an idle gap is named
 after the innermost host event on the benchmark's thread that covers its
-middle.
+middle. The line `XLA Ops` of a chip holds one event per execution of an
+HLO op, named by the op's HLO text (`%all-gather-start.3 = ...
+all-gather-start(...)`); `collectives` reads the collective ops there.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -21,6 +24,10 @@ import numpy as np
 
 WAVE_MARK = "chipbench.wave"
 TOP = 10
+# HLO opcodes of the collectives, each also as its async `-start` and
+# `-done`
+COLLECTIVE = re.compile(r"(all-gather|all-reduce|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?")
 
 
 def xplane_file(log_dir: str) -> str:
@@ -60,24 +67,31 @@ def _program_name(name: str) -> str:
     return re.sub(r"\(\d+\)$", "", name)
 
 
-def reduce(pd, chips: int) -> dict:
-    """Busy seconds per chip, device seconds per program, and the longest
-    idle gaps of a trace, over the benchmark's marked wave (the span named
-    WAVE_MARK)."""
-    host_line, window = None, None
+def _wave(pd) -> tuple:
+    """The host line that holds the span named WAVE_MARK, and the span's
+    (start, end); (None, None) where the trace has none."""
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
             for ev in line.events:
                 if ev.name == WAVE_MARK:
-                    host_line, window = line, (ev.start_ns, ev.end_ns)
-                    break
-            if host_line is not None:
-                break
-    devices = sorted((p for p in pd.planes
-                      if re.fullmatch(r"/device:TPU:\d+", p.name)),
-                     key=lambda p: p.name)[:chips]
+                    return line, (ev.start_ns, ev.end_ns)
+    return None, None
+
+
+def _chips(pd, chips: int) -> list:
+    return sorted((p for p in pd.planes
+                   if re.fullmatch(r"/device:TPU:\d+", p.name)),
+                  key=lambda p: p.name)[:chips]
+
+
+def reduce(pd, chips: int) -> dict:
+    """Busy seconds per chip, device seconds per program, and the longest
+    idle gaps of a trace, over the benchmark's marked wave (the span named
+    WAVE_MARK)."""
+    host_line, window = _wave(pd)
+    devices = _chips(pd, chips)
     spans, programs = [], {}
     for plane in devices:
         iv = []
@@ -109,6 +123,76 @@ def reduce(pd, chips: int) -> dict:
         "programs": {k: v / 1e9 / len(devices) for k, v in top_programs},
         "idle_gaps": sorted(by_name.items(), key=lambda kv: -kv[1])[:TOP],
     }
+
+
+def _opcode(text: str) -> str:
+    """The opcode of an op event named by its HLO text, `%name = shape
+    opcode(operands), ...`; the name itself where there is no text."""
+    name, eq, rest = text.partition(" = ")
+    if not eq:
+        return name.lstrip("%")
+    depth = 0
+    for i, ch in enumerate(rest):          # skip the shape, maybe a tuple
+        depth += (ch == "(") - (ch == ")")
+        if ch == " " and depth == 0:
+            return rest[i + 1:].split("(", 1)[0]
+    return ""
+
+
+def is_collective(text: str) -> bool:
+    """Whether an op event is a collective: by its opcode, or by its name
+    where XLA wraps one in another op (an async or fusion wrapper)."""
+    name = text.partition(" = ")[0].lstrip("%")
+    return bool(COLLECTIVE.fullmatch(_opcode(text))
+                or COLLECTIVE.match(name))
+
+
+def collectives(pd, chips: int) -> dict:
+    """Program -> [device seconds, seconds of its collective ops] over the
+    benchmark's marked wave, averaged over the chips used.
+
+    A program execution counts where it starts inside the wave. Its ops are
+    the leaf events of the chip's `XLA Ops` line that start inside it (an
+    event that the next event starts inside, as a while loop spans the ops
+    of its body, is no leaf; the line holds its events in order of their
+    start), so they never overlap one another: the seconds of its
+    collective ops (`is_collective`, an async start and its done each on
+    their own) are seconds in which the chip did nothing else. An async
+    collective's transfer between its start and its done, under other ops,
+    counts nothing. Empty where the trace has no chip plane."""
+    _, window = _wave(pd)
+    devices = _chips(pd, chips)
+    out: dict = {}
+    if window is None:
+        return out
+    lo, hi = window
+    for plane in devices:
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Modules" not in lines or "XLA Ops" not in lines:
+            continue
+        mods = sorted((ev.start_ns, ev.end_ns, _program_name(ev.name))
+                      for ev in lines["XLA Modules"].events
+                      if lo <= ev.start_ns < hi)
+        starts = [m[0] for m in mods]
+        for s, e, name in mods:
+            out.setdefault(name, [0.0, 0.0])[0] += (e - s) / 1e9
+
+        def count(ev):
+            """Puts a leaf op down to the program that covers its start."""
+            s = ev.start_ns
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and mods[i][1] >= s and is_collective(ev.name):
+                out[mods[i][2]][1] += ev.duration_ns / 1e9
+
+        prev = None
+        for ev in lines["XLA Ops"].events:
+            if prev is not None and ev.start_ns >= prev.end_ns:
+                count(prev)
+            prev = ev if lo <= ev.start_ns < hi else None
+        if prev is not None:
+            count(prev)
+    n = max(len(devices), 1)
+    return {k: [v[0] / n, v[1] / n] for k, v in out.items()}
 
 
 def _gaps(busy: np.ndarray, lo: float, hi: float) -> list:

@@ -156,8 +156,28 @@ class WindowClosed(Exception):
     """Raised at the engine's first step after the window's deadline."""
 
 
-def windowed_engine(cfg, params, serving: dict):
-    """The program's ServingEngine, set as the deployment runs it, with
+def mesh_page_pool(cfg, serving: dict, mesh):
+    """The engine's paged KV pool, of the engine's default size, laid out
+    over `mesh` by the program's own `PageAllocator.shard_pools`.
+
+    `ServingEngine(mesh=)` makes its pool whole on the first chip before
+    laying it out over the mesh, beside its decode cache, also made whole
+    there: at 32 of Qwen3-32B's layers the two (4.8 and 4.3 GB) do not fit
+    beside that chip's 8.6 GB of weights. Made here before the weights are
+    drawn, the whole pool has left the first chip when they arrive, and the
+    engine takes the laid-out one (`page_allocator=`)."""
+    from repro.models.cache_ops import PageAllocator
+    pages = (serving["slots"] + 4) * serving["max_len"] \
+        // serving["page_size"] + 1
+    alloc = PageAllocator(cfg, pages, serving["page_size"])
+    alloc.shard_pools(mesh)
+    return alloc
+
+
+def windowed_engine(cfg, params, serving: dict, mesh=None, pool=None):
+    """The program's ServingEngine, set as the deployment runs it (on
+    `mesh`, a serving mesh, with `pool` from `mesh_page_pool`, where the
+    configuration asks for one), with
     benchmark-side hooks: each step first checks the window's deadline;
     every request submitted is kept for the wave that submitted it; and for
     the first request of each prompt in `tap` (or the first one submitted,
@@ -167,16 +187,21 @@ def windowed_engine(cfg, params, serving: dict):
     round emitted (the cells serve with speculation; a token served by a
     plain decode step is left unread, which fails `unread_tokens`). Kept
     rows are copied on the device into `tap_buf`, a block of `max_new` rows
-    per tapped request, and read back once the window has closed: nothing
-    is copied to the host inside the window."""
+    per tapped request (replicated over the mesh), and read back once the
+    window has closed: nothing is copied to the host inside the window."""
     import jax
     import jax.numpy as jnp
     from repro.serving.engine import ServingEngine
 
     max_new = serving["max_new"]
     scratch = N_SAMPLE * max_new          # the row that takes unused picks
+    replicated, out = None, {}
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+        replicated = NamedSharding(mesh, PartitionSpec())
+        out = {"out_shardings": replicated}
 
-    @partial(jax.jit, donate_argnums=0)
+    @partial(jax.jit, donate_argnums=0, **out)
     def put(buf, logits, slot, n, base):
         """Rows slot*K .. slot*K+n-1 of `logits` (rows, K, V) into rows
         base .. base+n-1 of `buf`."""
@@ -250,15 +275,17 @@ def windowed_engine(cfg, params, serving: dict):
                                len(r.out) - n0)
             self._round_logits = None
 
+    on_mesh = {} if mesh is None else {"mesh": mesh, "page_allocator": pool}
     engine = Engine(cfg, params, slots=serving["slots"],
                     max_len=serving["max_len"], kv_layout="paged",
                     page_size=serving["page_size"],
                     chunk_size=serving["chunk_size"],
                     prefix_cache=serving["prefix_cache"],
-                    spec_decode=serving["spec_decode"])
+                    spec_decode=serving["spec_decode"], **on_mesh)
     engine.wave_requests = []
     engine.tap, engine.tap_first = frozenset(), False
-    engine.tap_buf = jnp.zeros((scratch + 1, cfg.vocab_size), jnp.float32)
+    engine.tap_buf = jnp.zeros((scratch + 1, cfg.vocab_size), jnp.float32,
+                               device=replicated)
     engine.tap_base, engine.tap_read, engine._tap_host = {}, {}, None
     return engine
 
@@ -474,7 +501,9 @@ def tapped_requests(engine, wave: dict) -> tuple:
 # ------------------------------------------------------------ set-up ---
 
 
-def build(conf: dict, mix: dict, seed: int) -> dict:
+def build(conf: dict, mix: dict, seed: int, mesh=None) -> dict:
+    """Set-up of a run; with `mesh`, the weights are drawn straight into the
+    program's serving layout over it and the engine runs on it."""
     import jax
     from repro.extract.served import ServedExtractor
     from repro.index.retriever import TwoLevelRetriever
@@ -491,10 +520,18 @@ def build(conf: dict, mix: dict, seed: int) -> dict:
     log(f"set-up: corpus, retriever and queries {time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
     reference = load_reference(conf)
-    params = reference.make_weights(conf, seed)
+    cfg = model_config(conf)
+    shardings = pool = None
+    if mesh is not None:
+        from repro.distributed.sharding import param_shardings
+        pool = mesh_page_pool(cfg, serving, mesh)
+        shardings = param_shardings(
+            cfg, jax.eval_shape(lambda: reference.make_weights(conf, seed)),
+            mesh, serving=True)
+    params = reference.make_weights(conf, seed, shardings)
     jax.block_until_ready(params)
     log(f"set-up: weights {time.perf_counter() - t:.2f} s")
-    engine = windowed_engine(model_config(conf), params, serving)
+    engine = windowed_engine(cfg, params, serving, mesh, pool)
     extractor = ServedExtractor(corpus, engine, max_new=serving["max_new"])
     return {"conf": conf, "mix": mix, "serving": serving, "corpus": corpus,
             "retriever": retriever, "queries": queries, "params": params,
@@ -512,6 +549,21 @@ def check_devices(chips: int, require_tpu: bool):
         raise SystemExit(f"chipbench: the cell needs {chips} chips, JAX "
                          f"found {len(devices)}")
     return devices[:chips]
+
+
+def serving_mesh(serving: dict, devices: list):
+    """The (data, model) serving mesh that a configuration asks for under
+    `serving["mesh"]`, over the cell's devices; None where it asks for
+    none. Its size has to be the cell's number of chips."""
+    shape = serving.get("mesh")
+    if shape is None:
+        return None
+    if int(np.prod(shape)) != len(devices):
+        raise SystemExit(f"chipbench: the serving mesh {shape} needs "
+                         f"{int(np.prod(shape))} chips, the cell asks for "
+                         f"{len(devices)}")
+    from repro.launch.mesh import _make_mesh
+    return _make_mesh(tuple(shape), ("data", "model"), devices=devices)
 
 
 # ----------------------------------------------------------------- run ---
@@ -544,7 +596,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     conf = conf or load_config(cell["config"])
     mix = traffic.load_mix(cell["traffic"])
 
-    ctx = build(conf, mix, seed)
+    ctx = build(conf, mix, seed, serving_mesh(conf["serving"], devices))
     t = time.perf_counter()
     want = oracle_rows(ctx["corpus"], ctx["retriever"], ctx["queries"],
                        ctx["serving"])
@@ -611,12 +663,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     readings = None
     if trace:
         import shutil
-        reduced = devtrace.reduce(
-            devtrace.load(devtrace.xplane_file(trace_dir)), len(devices))
+        pd = devtrace.load(devtrace.xplane_file(trace_dir))
+        reduced = devtrace.reduce(pd, len(devices))
+        t = time.perf_counter()
+        collectives = devtrace.collectives(pd, len(devices))
+        log(f"trace: collective ops read in {time.perf_counter() - t:.2f} s; "
+            f"program -> [device s, collective s] per chip: {collectives}")
+        del pd
         shutil.rmtree(trace_dir, ignore_errors=True)
         readings = Readings(conf, waves[0], K, reduced,
                             peaks or load_peaks(dev.device_kind),
-                            len(devices))
+                            len(devices), collectives)
 
     # ---- the comparison, once the window has closed and the engine is freed
     rows_bad = sum(_canon(r) != want[i] for w in whole
@@ -690,11 +747,12 @@ def _applies(metric: dict, workload: str) -> bool:
 
 class Readings:
     """What the per-layer readers read: the traced wave's counters, its
-    requests and its reduced device trace."""
+    requests, its reduced device trace and, by program, the device seconds
+    and collective seconds of its chips (`devtrace.collectives`)."""
 
-    def __init__(self, conf, wave, k, trace, peaks, chips):
+    def __init__(self, conf, wave, k, trace, peaks, chips, collectives=None):
         self.conf, self.k, self.trace, self.peaks = conf, k, trace, peaks
-        self.chips = chips
+        self.chips, self.collectives = chips, collectives
         self.counts = load_module(conf["counts"])
         self.slots = conf["serving"]["slots"]
         self.whole = wave["whole"]

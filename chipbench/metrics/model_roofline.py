@@ -7,7 +7,10 @@ peak and its least bytes over the HBM bandwidth: every call reads the
 weights once, prefill writes the KV of the tokens it fills, and each verify
 round reads the prompt KV of the requests in it (`counts.py`). Summing per
 kind is a floor of the per-call sum, so the share cannot pass the true one.
-Moves queries_per_min."""
+Per chip: on a serving mesh each of the wave's chips does 1/chips of the
+FLOPs and reads 1/chips of the weights and KV, so the least time is divided
+by the chips, against the device time averaged over them (collectives count
+nothing, so the share stays a floor). Moves queries_per_min."""
 
 
 def read(r):
@@ -24,4 +27,4 @@ def read(r):
                    (rounds * per_call + w["decode_bytes"]) / bw))
     device = sum(s for name, s in r.trace["programs"].items()
                  if "l2_rank" not in name)
-    return 100.0 * least / device if device else None
+    return 100.0 * least / r.chips / device if device else None

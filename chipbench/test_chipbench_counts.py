@@ -6,7 +6,7 @@ import pytest
 
 from chipbench import counts, harness
 
-CONFIGS = ["qwen2.5-3b", "qwen3-32b-8L"]
+CONFIGS = ["qwen2.5-3b", "qwen3-32b-8L", "qwen3-32b-32L-tp4"]
 
 
 def _program_tree(conf):
@@ -43,6 +43,16 @@ def test_published_sizes():
     assert round(counts.total_params(big) / 1e9, 2) == 5.46
     assert counts.kv_bytes_per_token(small) == 36_864
     assert counts.kv_bytes_per_token(big) == 32_768
+
+
+def test_published_sizes_of_the_tp4_stage():
+    """32 layers of Qwen3-32B with the embedding and the head: 17.16 B
+    parameters, 8.58 GB a chip of four; its KV 131,072 B a token."""
+    conf = harness.load_config("qwen3-32b-32L-tp4")
+    n = counts.total_params(conf)
+    assert round(n / 1e9, 2) == 17.16
+    assert round(2 * n / 4 / 1e9, 2) == 8.58
+    assert counts.kv_bytes_per_token(conf) == 131_072
 
 
 def test_useful_work_floors():

@@ -99,3 +99,18 @@ def test_peaks_known_device_only():
     assert harness.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError):
         harness.load_peaks("cpu")
+
+
+def test_serving_mesh_spans_each_cells_chips():
+    """A configuration's serving mesh holds as many chips as every cell
+    that runs it asks for (one chip where it has no mesh), and at most
+    half of the cells, rounded down (one always may), ask for four."""
+    for w in BENCH["workloads"]:
+        mesh = harness.load_config(w["config"])["serving"].get("mesh", [1])
+        assert len(mesh) in (1, 2) and all(n >= 1 for n in mesh)
+        n = 1
+        for k in mesh:
+            n *= k
+        assert n == w["chips"], (w["name"], mesh)
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 2)

@@ -21,14 +21,14 @@ class _Req:
         self.out, self.accepted_tokens = [1] * out, accepted
 
 
-def _readings(trace, engine=ENGINE):
+def _readings(trace, engine=ENGINE, chips=1):
     conf = harness.load_config("qwen2.5-3b")
     wave = {"whole": True, "seconds": 10.0, "engine": dict(engine),
             "scheduler": {"rounds": 4, "submitted": 100},
             "ledger": {"extractions": 100}, "retrieval_s": 0.5,
             "requests": [_Req(400, 120, 24, 2) for _ in range(100)]}
     return harness.Readings(conf, wave, 2, trace,
-                            harness.load_peaks("TPU v5 lite"), 1)
+                            harness.load_peaks("TPU v5 lite"), chips)
 
 
 def _trace(programs=PROGRAMS):
@@ -76,3 +76,39 @@ def test_phase_rooflines_without_their_program(name, program):
     assert reader.read(_readings(_trace(others))) is None
     # the parent engine's programs were one `jit_fn`
     assert reader.read(_readings(_trace({"jit_fn": 5.5}))) is None
+
+
+def _least(r, kind):
+    """The least time of a kind of call on one chip, as the readers had it
+    before they read per chip."""
+    c, e = r.counts, r.engine
+    w = c.useful_work(r.conf, r.requests, e["prefill_tokens"])
+    per_call = c.weight_bytes_per_call(r.conf)
+    flops, bw = r.peaks["bf16_flops_per_s"], r.peaks["hbm_bytes_per_s"]
+    if kind == "prefill":
+        return max(w["prefill_flops"] / flops,
+                   (e["prefill_chunks"] * per_call + w["prefill_bytes"]) / bw)
+    return max(w["decode_flops"] / flops,
+               (e["decode_steps"] * per_call + w["decode_bytes"]) / bw)
+
+
+@pytest.mark.parametrize("name", ["prefill_roofline", "verify_roofline",
+                                  "model_roofline"])
+def test_phase_rooflines_read_per_chip(name):
+    """On one chip each reader gives what it gave before it read per chip,
+    bit for bit; on four, where each chip does a quarter of the work in the
+    device time averaged over them, a quarter of it."""
+    one = _readings(_trace())
+    if name == "model_roofline":
+        least = _least(one, "prefill") + _least(one, "verify")
+        device = PROGRAMS["jit_prefill_chunk"] + PROGRAMS["jit_verify_round"]
+    else:
+        kind = name.split("_")[0]
+        least = _least(one, kind)
+        device = PROGRAMS[f"jit_{kind}_" + ("chunk" if kind == "prefill"
+                                            else "round")]
+    before = 100.0 * least / device
+    reader = harness.load_metric(name)
+    assert reader.read(one) == before
+    assert reader.read(_readings(_trace(), chips=4)) == \
+        pytest.approx(before / 4, rel=1e-15)
