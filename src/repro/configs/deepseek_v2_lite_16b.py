@@ -1,9 +1,15 @@
-"""deepseek-v2-lite-16b [moe]: MLA attention + fine-grained MoE.
+"""deepseek-v2-lite-16b [moe]: MLA attention + fine-grained MoE, as the
+published config.json (huggingface.co/deepseek-ai/DeepSeek-V2-Lite) states it.
 
-27L, d_model=2048, 16H, vocab=102400. MLA: kv_lora=512, decoupled rope dim 64.
-MoE: 2 shared + 64 routed experts, top-6, expert d_ff=1408; first layer dense
-(d_ff=10944). Assignment line says both "64e top-6" and "160 routed"; 160
-routed belongs to full V2 — V2-*Lite* has 64 routed (see DESIGN.md §3).
+27 layers, d_model 2048, 16 heads, vocab 102400, untied head, RMSNorm eps
+1e-6. MLA without query compression (q_lora_rank null): kv_lora_rank 512,
+qk_nope 128, decoupled rope dim 64, v_head 128; rope_theta 10000 with YaRN
+scaling (factor 40 over 4096 original positions, beta_fast 32, beta_slow 1,
+mscale = mscale_all_dim = 0.707, so the softmax scale gains mscale^2).
+MoE: the first layer dense (d_ff 10944), then 26 layers of 64 routed experts
+(width 1408, top-6 by greedy softmax gating, no renormalisation of the top-6
+weights, routed_scaling_factor 1) plus 2 shared experts. "160 routed"
+belongs to the full V2; V2-Lite has 64 (see DESIGN.md §3).
 [arXiv:2405.04434; hf]
 """
 from repro.models.config import ModelConfig
@@ -14,7 +20,12 @@ CONFIG = ModelConfig(
     d_ff=10944, vocab_size=102400,
     use_mla=True, kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
     n_experts=64, moe_top_k=6, n_shared_experts=2, expert_d_ff=1408,
-    first_dense_layers=1,
+    first_dense_layers=1, norm_topk_prob=False, routed_scaling_factor=1.0,
+    rope_theta=10000.0, norm_eps=1e-6,
+    rope_scaling={"type": "yarn", "factor": 40,
+                  "original_max_position_embeddings": 4096,
+                  "beta_fast": 32, "beta_slow": 1,
+                  "mscale": 0.707, "mscale_all_dim": 0.707},
     activation="silu",
 )
 

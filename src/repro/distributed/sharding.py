@@ -188,13 +188,9 @@ def batch_spec(mesh, batch_size: int) -> tuple:
     return tuple(axes) if axes else ()
 
 
-def make_constrain(mesh, batch_size: int, *, ep_moe: bool = False,
-                   serving: bool = False):
+def make_constrain(mesh, batch_size: int, *, serving: bool = False):
     """Activation sharding hook threaded through model forward/decode.
 
-    ep_moe: pin MoE dispatch/combine buffers (E, C, d) to P(data, None, None)
-    — experts live on data shards, so GSPMD moves *tokens* (all-to-all)
-    instead of all-gathering index tensors and reducing dispatch products.
     serving: gather the activations around the output projections
     ("gather") for the column-parallel serving layout; a no-op otherwise."""
     baxes = batch_spec(mesh, batch_size)
@@ -205,12 +201,6 @@ def make_constrain(mesh, batch_size: int, *, ep_moe: bool = False,
             spec = P(b, *([None] * (x.ndim - 1)))
         elif kind == "logits":
             spec = P(b, *([None] * (x.ndim - 2)), TP)
-        elif kind == "moe_dispatch" and ep_moe:
-            e_ax = FSDP if x.shape[0] % mesh.shape[FSDP] == 0 else None
-            spec = P(e_ax, *([None] * (x.ndim - 1)))
-        elif kind == "moe_grouped":
-            g_ax = FSDP if x.shape[0] % mesh.shape[FSDP] == 0 else None
-            spec = P(g_ax, *([None] * (x.ndim - 1)))
         else:
             return x
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

@@ -59,12 +59,8 @@ def probe_config(cfg: ModelConfig, n: int) -> ModelConfig:
 from repro.distributed.sharding import _ROLES as _BASE_ROLES
 
 VARIANT_OVERRIDES = {
-    # expert parallelism: experts over `data`, ff over `model` — dispatch
-    # moves tokens (all-to-all), weights stay put
+    # expert parallelism: expert weights over `data`, ff over `model`
     "ep_moe": {"w_gate": "f.t", "w_up": "f.t", "w_down": "ft."},
-    # grouped-local dispatch: groups = data shards; expert weights replicated
-    # over data (ff over model) so per-group expert compute is fully local
-    "ep_grouped": {"w_gate": "..t", "w_up": "..t", "w_down": ".t."},
     # weight-stationary serving: drop FSDP (replicate over `data`), keep TP —
     # decode must move activations (tiny), not weights (huge)
     "serve_ws": {n: r.replace("f", ".") for n, r in _BASE_ROLES.items()},
@@ -81,8 +77,6 @@ def build_lowered(arch: str, shape_name: str, mesh, *, probe: int = 0,
     shape = SHAPES[shape_name]
     mlayers.set_attention_impl("dense" if dense_attn else None)
     attn_impl = None
-    if variant == "ep_grouped":
-        mlayers.set_moe_groups(mesh.shape["data"])
     if variant in ("seq_decode", "serve_ws_seqdec"):
         from repro.distributed.decode import make_seq_sharded_decode_attn
         attn_impl = make_seq_sharded_decode_attn(mesh)
@@ -128,7 +122,6 @@ def build_lowered(arch: str, shape_name: str, mesh, *, probe: int = 0,
         return cfg, shape, lowered, meta
     finally:
         mlayers.set_attention_impl(None)
-        mlayers.set_moe_groups(0)
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, *, probe: int = 0,
@@ -204,8 +197,7 @@ def main():
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--variant", default="baseline",
                     choices=["baseline", "seq_decode", "ep_moe", "serve_ws",
-                             "serve_ws_seqdec", "serve_ws2d", "serve_ws2d_seqdec",
-                             "ep_grouped"])
+                             "serve_ws_seqdec", "serve_ws2d", "serve_ws2d_seqdec"])
     args = ap.parse_args()
 
     archs = [args.arch] if args.arch else ARCH_IDS

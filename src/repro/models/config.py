@@ -30,17 +30,27 @@ class ModelConfig:
     qk_norm: bool = False             # qwen3 per-head RMSNorm on q/k
     use_rope: bool = True             # whisper uses learned absolute positions
     rope_theta: float = 10_000.0
+    # rope scaling as the published config.json's `rope_scaling` gives it
+    # (a dict, stored as sorted (key, value) pairs so that the config stays
+    # hashable as a static jit argument); {"type": "yarn", ...} applies YaRN
+    # to MLA's rope dimensions and softmax scale. Empty: no scaling.
+    rope_scaling: tuple = ()
     max_position: int = 1 << 20       # learned-abs position table size cap
     gated_mlp: bool = True            # llama-style gate/up/down (3 matrices)
     activation: str = "silu"          # silu | squared_relu | gelu
 
-    # MoE
+    # MoE: the router scores all `n_experts`; this device holds the
+    # contiguous block of `n_held_experts` (0: all) from `held_expert_start`
+    # (its share under expert parallelism) and computes their part alone
     n_experts: int = 0
+    n_held_experts: int = 0
+    held_expert_start: int = 0
     n_shared_experts: int = 0
     moe_top_k: int = 0
     expert_d_ff: int = 0
     first_dense_layers: int = 0       # deepseek-v2: first layer(s) dense
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True       # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0
     router_aux_coef: float = 0.01
 
     # MLA (deepseek-v2)
@@ -80,10 +90,28 @@ class ModelConfig:
     dtype: str = "bfloat16"           # params/compute dtype for deployment
     logit_dtype: str = "float32"
 
+    def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+
     # ---- derived ----
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def yarn(self):
+        """The YaRN parameters (a dict) where `rope_scaling` asks for YaRN,
+        else None."""
+        sc = dict(self.rope_scaling)
+        return sc if sc.get("type") == "yarn" else None
+
+    @property
+    def held_experts(self) -> range:
+        """The routed experts this device holds."""
+        n = self.n_held_experts or self.n_experts
+        return range(self.held_expert_start, self.held_expert_start + n)
 
     @property
     def resolved_dt_rank(self) -> int:
@@ -158,7 +186,8 @@ class ModelConfig:
         # dense / moe / vlm
         per_layer_attn = attn_params()
         if self.family == "moe" or self.n_experts:
-            routed = self.n_experts * mlp_params(self.expert_d_ff or self.d_ff)
+            routed = len(self.held_experts) * \
+                mlp_params(self.expert_d_ff or self.d_ff)
             shared = self.n_shared_experts * mlp_params(self.expert_d_ff or self.d_ff)
             router = d * self.n_experts
             moe_layers = self.num_layers - self.first_dense_layers

@@ -12,6 +12,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .config import ModelConfig
@@ -54,13 +55,47 @@ def rope_freqs(hd: int, theta: float):
     return theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd) (hd even); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term (DeepSeek-V2 `yarn_get_mscale`)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(hd: int, theta: float, yarn: dict):
+    """YaRN inverse frequencies of a `hd`-dim rope (DeepSeek-V2's
+    `DeepseekV2YarnRotaryEmbedding`): the base frequencies where a
+    dimension turns more than `beta_fast` times over the original context,
+    the frequencies divided by `factor` where it turns fewer than
+    `beta_slow` times, and a linear ramp between. Returns (inv_freq (hd/2,)
+    float32, the cos/sin scale `mscale / mscale_all_dim`)."""
+    factor, orig = float(yarn["factor"]), yarn["original_max_position_embeddings"]
+
+    def corr_dim(rot):
+        return hd * math.log(orig / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    lo = max(math.floor(corr_dim(yarn["beta_fast"])), 0)
+    hi = min(math.ceil(corr_dim(yarn["beta_slow"])), hd - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
+    ramp = np.clip((np.arange(hd // 2) - lo) / (hi - lo), 0.0, 1.0)
+    inv = extra / factor * ramp + extra * (1.0 - ramp)
+    scale = yarn_mscale(factor, yarn.get("mscale", 1.0)) / \
+        yarn_mscale(factor, yarn.get("mscale_all_dim", 0.0))
+    return jnp.asarray(inv, jnp.float32), scale
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, hd) (hd even); positions: broadcastable to (..., S).
+    `yarn`: YaRN parameters (`ModelConfig.yarn`), or None for plain rope."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if yarn is None:
+        freqs, scale = rope_freqs(hd, theta), 1.0        # (hd/2,)
+    else:
+        freqs, scale = yarn_freqs(hd, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(ang)[..., None, :]                    # (..., S, 1, hd/2)
     sin = jnp.sin(ang)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -496,16 +531,32 @@ def mla_init(cfg: ModelConfig, key):
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """`(qk_nope_dim + qk_rope_dim) ** -0.5`, times YaRN's `mscale ** 2`
+    where the config scales its rope with `mscale_all_dim` (DeepSeek-V2's
+    `DeepseekV2Attention.softmax_scale`)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    yarn = cfg.yarn
+    if yarn and yarn.get("mscale_all_dim"):
+        scale *= yarn_mscale(float(yarn["factor"]), yarn["mscale_all_dim"]) ** 2
+    return scale
+
+
 def mla_project(cfg: ModelConfig, p, x, positions):
-    """Shared q / compressed-kv projections. Returns q_nope,q_rope,c_kv,k_rope."""
-    nd, rd = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = jnp.einsum("bsd,dhe->bshe", x, p["wq"])
+    """Shared q / compressed-kv projections. Returns q_nope,q_rope,c_kv,k_rope.
+
+    The rope dimensions rotate as two halves (`apply_rope`); DeepSeek-V2's
+    checkpoints rotate interleaved pairs, a fixed permutation of the rope
+    columns of `wq` and `w_dkv` that a checkpoint loader would apply."""
+    nd = cfg.qk_nope_dim
+    q = proj("bsd,dhe->bshe", x, p["wq"])
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    dkv = x @ p["w_dkv"]
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
+    dkv = proj("bsd,dr->bsr", x, p["w_dkv"])
     c_kv, k_rope = dkv[..., : cfg.kv_lora_rank], dkv[..., cfg.kv_lora_rank:]
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta,
+                        cfg.yarn)[..., 0, :]
     return q_nope, q_rope, c_kv, k_rope
 
 
@@ -518,7 +569,7 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions):
     v = jnp.einsum("bsr,rhe->bshe", c_kv, p["w_uv"])
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, cfg.qk_rope_dim))], axis=-1)
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     qg = q[:, :, :, None, :]
     dense = S <= 2048
     if _ATTN_OVERRIDE is not None:
@@ -532,31 +583,44 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions):
     return out, (c_kv, k_rope)
 
 
+def _mla_absorbed(cfg: ModelConfig, p, q_nope, q_rope, ckv_cache,
+                  krope_cache, ok):
+    """Absorbed-matmul MLA attention over a compressed cache (DeepSeek-V2's
+    own optimisation): the per-head K/V up-projections fold into the query
+    and context sides, so the cache stays r + rope_dim numbers a token.
+    q_nope (B, S, H, nd), q_rope (B, S, H, rd), caches (B, T, r) and
+    (B, T, rd); `ok` (B, S, T) marks the positions each query attends.
+    Returns the output projection's input (B, S, H, vd)."""
+    with jax.named_scope("attention"):
+        q_abs = jnp.einsum("bshe,rhe->bshr", q_nope, p["w_uk"])       # (B,S,H,r)
+        s = jnp.einsum("bshr,btr->bhst", q_abs, ckv_cache,
+                       preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bshe,bte->bhst", q_rope, krope_cache,
+                           preferred_element_type=jnp.float32)
+        s = s * mla_softmax_scale(cfg)
+        s = jnp.where(ok[:, None, :, :], s, -jnp.inf)
+        pr = jax.nn.softmax(s, axis=-1)
+        ctx = jnp.einsum("bhst,btr->bshr", pr.astype(ckv_cache.dtype),
+                         ckv_cache)
+        return jnp.einsum("bshr,rhe->bshe", ctx, p["w_uv"])
+
+
 def mla_decode_apply(cfg: ModelConfig, p, x, *, pos, ckv_cache, krope_cache):
-    """Absorbed-matmul MLA decode (DeepSeek-V2's own optimization): the
-    per-head K/V up-projections fold into the query/context sides so the
-    cache stays compressed (r + rope_dim per token). `pos` scalar or (B,)."""
+    """Single-token MLA decode in the absorbed form (`_mla_absorbed`).
+    `pos` scalar or (B,)."""
     B, S, _ = x.shape
-    H = cfg.n_heads
-    pp = jnp.broadcast_to(jnp.asarray(pos).reshape(-1, 1) if jnp.asarray(pos).ndim
-                          else jnp.full((B, S), pos), (B, S))
-    q_nope, q_rope, c_kv, k_rope = mla_project(cfg, p, x, pp)
-    ckv_cache = cache_write(ckv_cache, c_kv, pos)
-    krope_cache = cache_write(krope_cache, k_rope, pos)
-    q_abs = jnp.einsum("bshe,rhe->bshr", q_nope, p["w_uk"])          # (B,1,H,r)
-    s = jnp.einsum("bshr,btr->bhst", q_abs, ckv_cache, preferred_element_type=jnp.float32)
-    s = s + jnp.einsum("bshe,bte->bhst", q_rope, krope_cache, preferred_element_type=jnp.float32)
-    s = s * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
     posv = jnp.asarray(pos)
-    if posv.ndim == 0:
-        ok = jnp.arange(ckv_cache.shape[1])[None] <= posv
-    else:
-        ok = jnp.arange(ckv_cache.shape[1])[None, :] <= posv[:, None]
-    s = jnp.where(ok[:, None, None, :], s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhst,btr->bshr", pr.astype(ckv_cache.dtype), ckv_cache)
-    out = jnp.einsum("bshr,rhe->bshe", ctx, p["w_uv"])
-    out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
+    pp = jnp.broadcast_to(posv.reshape(-1, 1) if posv.ndim
+                          else jnp.full((B, S), pos), (B, S))
+    with jax.named_scope("qkv_proj"):
+        q_nope, q_rope, c_kv, k_rope = mla_project(cfg, p, x, pp)
+    with jax.named_scope("attention"):
+        ckv_cache = cache_write(ckv_cache, c_kv, pos)
+        krope_cache = cache_write(krope_cache, k_rope, pos)
+    ok = jnp.arange(ckv_cache.shape[1])[None, None, :] <= pp[:, :, None]
+    out = _mla_absorbed(cfg, p, q_nope, q_rope, ckv_cache, krope_cache, ok)
+    with jax.named_scope("out_proj"):
+        out = proj("bshe,hed->bsd", out, p["wo"])
     return out, (ckv_cache, krope_cache)
 
 
@@ -567,43 +631,31 @@ def mla_chunk_apply(cfg: ModelConfig, p, x, *, start, ckv_cache, krope_cache):
     `start` may be a scalar or a per-row (B,) vector (batched verify)."""
     B, C, _ = x.shape
     q_pos = chunk_positions(start, B, C)                      # (B, C)
-    q_nope, q_rope, c_kv, k_rope = mla_project(cfg, p, x, q_pos)
-    ckv_cache = cache_write_chunk(ckv_cache, c_kv, start)
-    krope_cache = cache_write_chunk(krope_cache, k_rope, start)
-    q_abs = jnp.einsum("bshe,rhe->bshr", q_nope, p["w_uk"])
-    s = jnp.einsum("bshr,btr->bhst", q_abs, ckv_cache,
-                   preferred_element_type=jnp.float32)
-    s = s + jnp.einsum("bshe,bte->bhst", q_rope, krope_cache,
-                       preferred_element_type=jnp.float32)
-    s = s * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    with jax.named_scope("qkv_proj"):
+        q_nope, q_rope, c_kv, k_rope = mla_project(cfg, p, x, q_pos)
+    with jax.named_scope("attention"):
+        ckv_cache = cache_write_chunk(ckv_cache, c_kv, start)
+        krope_cache = cache_write_chunk(krope_cache, k_rope, start)
     ok = jnp.arange(ckv_cache.shape[1])[None, None, :] <= q_pos[:, :, None]
-    s = jnp.where(ok[:, None, :, :], s, -jnp.inf)
-    pr = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhst,btr->bshr", pr.astype(ckv_cache.dtype), ckv_cache)
-    out = jnp.einsum("bshr,rhe->bshe", ctx, p["w_uv"])
-    out = jnp.einsum("bshe,hed->bsd", out, p["wo"])
+    out = _mla_absorbed(cfg, p, q_nope, q_rope, ckv_cache, krope_cache, ok)
+    with jax.named_scope("out_proj"):
+        out = proj("bshe,hed->bsd", out, p["wo"])
     return out, (ckv_cache, krope_cache)
 
 
 # ---------------------------------------------------------------- MoE ------
 
-_MOE_GROUPS = 0  # >1: grouped-local dispatch (expert-parallel layouts)
-
-
-def set_moe_groups(g):
-    global _MOE_GROUPS
-    _MOE_GROUPS = int(g)
-
 
 def moe_init(cfg: ModelConfig, key):
     d, E, ff = cfg.d_model, cfg.n_experts, cfg.expert_d_ff or cfg.d_ff
+    n = len(cfg.held_experts)
     ks = jax.random.split(key, 5)
     std = 0.02
     p = {
         "router": jax.random.normal(ks[0], (d, E), jnp.float32) * std,
-        "w_gate": jax.random.normal(ks[1], (E, d, ff), jnp.float32) * std,
-        "w_up": jax.random.normal(ks[2], (E, d, ff), jnp.float32) * std,
-        "w_down": jax.random.normal(ks[3], (E, ff, d), jnp.float32) * std / math.sqrt(2 * cfg.num_layers),
+        "w_gate": jax.random.normal(ks[1], (n, d, ff), jnp.float32) * std,
+        "w_up": jax.random.normal(ks[2], (n, d, ff), jnp.float32) * std,
+        "w_down": jax.random.normal(ks[3], (n, ff, d), jnp.float32) * std / math.sqrt(2 * cfg.num_layers),
     }
     if cfg.n_shared_experts:
         sh_ff = ff * cfg.n_shared_experts
@@ -616,102 +668,106 @@ def moe_init(cfg: ModelConfig, key):
     return p
 
 
-def moe_capacity(cfg: ModelConfig, T: int) -> int:
-    C = int(math.ceil(cfg.capacity_factor * cfg.moe_top_k * T / cfg.n_experts))
-    return max(8, -(-C // 8) * 8)  # round up to multiple of 8
-
-
-def _moe_dispatch_group(cfg: ModelConfig, p, x2, C):
-    """Dispatch+compute+combine for one token group (no cross-group refs:
-    under a (groups=data-shards) reshape every index op stays shard-local)."""
-    T, d = x2.shape
-    E, K = cfg.n_experts, cfg.moe_top_k
-    logits = (x2 @ p["router"]).astype(jnp.float32)
+def moe_route(cfg: ModelConfig, router, x):
+    """Gate of every token over all `n_experts` (DeepSeek-V2's `MoEGate`,
+    greedy top-k, softmax scoring): logits in float32 from float32 inputs,
+    softmax, the top `moe_top_k` probabilities (renormalised only where
+    `norm_topk_prob`) times `routed_scaling_factor`. Returns (probs (..., E),
+    top-k weights (..., K) float32, top-k expert ids (..., K))."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = lax.top_k(probs, K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    flat_e = top_i.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), K)
-    flat_w = top_w.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    pos = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1, flat_e[:, None], axis=1)[:, 0]
-    keep = pos < C
-    slot = jnp.where(keep, flat_e * C + pos, E * C)
-    xe = jnp.zeros((E * C + 1, d), x2.dtype).at[slot].set(x2[flat_t])
-    xe = xe[: E * C].reshape(E, C, d)
-    act = activation_fn(cfg.activation)
-    h = act(jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])) *         jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
-    ye = jnp.einsum("ecf,efd->ecd", h, p["w_down"])
-    ye_flat = jnp.concatenate([ye.reshape(E * C, d), jnp.zeros((1, d), ye.dtype)], axis=0)
-    back = ye_flat[slot] * (flat_w * keep)[:, None].astype(ye.dtype)
-    return jnp.zeros((T, d), x2.dtype).at[flat_t].add(back)
+    top_w, top_i = lax.top_k(probs, cfg.moe_top_k)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-20)
+    return probs, top_w * cfg.routed_scaling_factor, top_i
 
 
-def moe_apply(cfg: ModelConfig, p, x, *, return_aux=False, constrain=None):
-    """Capacity-based top-k MoE with gather/scatter dispatch (no giant one-hot
-    einsums). x: (B, S, d). Tokens over capacity are dropped (GShard-style).
+# Up to this many tokens a call, `moe_apply` runs every held expert on every
+# token: the dense form then costs no more than reading the held experts'
+# weights, which any form does once. It does one multiply-add per weight
+# (2 bytes in bf16) per token, so T tokens do about T FLOPs per weight byte
+# read, and a TPU v5e is bound by HBM below about 240 (197 TFLOP/s of bf16
+# over 819 GB/s). Every serving call (a 32-token chunk, a 32-slot decode
+# step, a 32 x 5-token verify round) lies below it; a training step lies far
+# above it and takes the sorted form, whose FLOPs follow the routed picks.
+MOE_DENSE_MAX_TOKENS = 256
 
-    `constrain(x, kind)` hook: under expert parallelism the launcher pins
-    the dispatch buffer to P(data, None, None) (experts sharded over data) so
-    the scatter becomes a token all-to-all instead of index all-gathers."""
+
+def _held_experts_dense(p, x, gate, act, constrain):
+    """Every held expert on every token, each output weighted by the token's
+    gate weight for that expert, `gate` (B, S, n_held) (0 where the token
+    did not pick it)."""
+    h = act(proj("bsd,edf->bsef", x, p["w_gate"])) * \
+        proj("bsd,edf->bsef", x, p["w_up"])
+    h = (h.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+    return proj("bsef,efd->bsd", constrain(h, "gather"), p["w_down"])
+
+
+def _held_experts_sorted(p, x, top_w, top_i, start, act):
+    """Each (token, pick) on a held expert, sorted by expert and run as one
+    grouped matmul (`lax.ragged_dot`) a weight matrix; picks of experts not
+    held sort last, outside every group, and add nothing."""
     B, S, d = x.shape
-    if _MOE_GROUPS > 1 and (B * S) % _MOE_GROUPS == 0:
-        G = _MOE_GROUPS
-        xg = x.reshape(G, B * S // G, d)
-        C_g = moe_capacity(cfg, B * S // G)
-        y = jax.vmap(lambda xx: _moe_dispatch_group(cfg, p, xx, C_g))(xg)
-        if constrain is not None:
-            y = constrain(y, "moe_grouped")
-        y = y.reshape(B * S, d)
-        if cfg.n_shared_experts:
-            sp = p["shared"]
-            act = activation_fn(cfg.activation)
-            x2s = x.reshape(B * S, d)
-            y = y + (act(x2s @ sp["w_gate"]) * (x2s @ sp["w_up"])) @ sp["w_down"]
-        y = y.reshape(B, S, d)
-        if not return_aux:
-            return y
-        return y, jnp.float32(0.0)
-    x2 = x.reshape(B * S, d)
-    T, E, K = B * S, cfg.n_experts, cfg.moe_top_k
-    C = moe_capacity(cfg, T)
+    n, K = p["w_gate"].shape[0], top_i.shape[-1]
+    e = (top_i - start).reshape(-1)                        # (T*K,)
+    e = jnp.where((e >= 0) & (e < n), e, n)
+    order = jnp.argsort(e, stable=True)
+    tok = order // K
+    sizes = jnp.bincount(e, length=n + 1)[:n].astype(jnp.int32)
+    rows = x.reshape(B * S, d)[tok]
 
-    logits = (x2 @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = lax.top_k(probs, K)                     # (T, K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    def grouped(a, w):
+        return lax.ragged_dot(a, w, sizes)
+    h = act(grouped(rows, p["w_gate"])) * grouped(rows, p["w_up"])
+    ys = grouped(h, p["w_down"]).astype(jnp.float32)
+    w = top_w.reshape(-1)[order]
+    ys = jnp.where((e[order] < n)[:, None], ys * w[:, None], 0.0)
+    y = jnp.zeros((B * S, d), jnp.float32).at[tok].add(ys)
+    return y.reshape(B, S, d).astype(x.dtype)
 
-    flat_e = top_i.reshape(-1)                             # (T*K,)
-    flat_t = jnp.repeat(jnp.arange(T), K)
-    flat_w = top_w.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)    # (T*K, E)
-    pos = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - 1, flat_e[:, None], axis=1)[:, 0]
-    keep = pos < C
-    slot = jnp.where(keep, flat_e * C + pos, E * C)        # E*C = drop slot
 
-    xe = jnp.zeros((E * C + 1, d), x.dtype).at[slot].set(x2[flat_t])
-    xe = xe[: E * C].reshape(E, C, d)
-    if constrain is not None:
-        xe = constrain(xe, "moe_dispatch")
+@jax.named_scope("mlp")
+def moe_apply(cfg: ModelConfig, p, x, constrain=no_constrain):
+    """The expert layer as one device of an expert-parallel deployment
+    computes it: the router scores all `n_experts`, this device's held
+    experts (`cfg.held_experts`) add their gated outputs, and the shared
+    experts add theirs once. Experts this device does not hold add nothing.
+
+    No token is dropped and none depends on the others in its call. Up to
+    `MOE_DENSE_MAX_TOKENS` tokens every held expert runs on every token,
+    weighted by the token's gate (`_held_experts_dense`); above it each
+    pick runs on its expert alone (`_held_experts_sorted`). The two forms
+    differ only in the rounding of their sums.
+
+    x: (B, S, d). Returns (out (B, S, d), held (B, S) int32: the token's
+    top-k picks that fall on the held experts, the load-balance aux loss
+    (Switch/GShard, E * sum_e f_e * P_e))."""
+    E = cfg.n_experts
+    held_ids = cfg.held_experts
     act = activation_fn(cfg.activation)
-    h = act(jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])) * jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
-    ye = jnp.einsum("ecf,efd->ecd", h, p["w_down"])        # (E, C, d)
-    if constrain is not None:
-        ye = constrain(ye, "moe_dispatch")
-
-    ye_flat = jnp.concatenate([ye.reshape(E * C, d), jnp.zeros((1, d), ye.dtype)], axis=0)
-    back = ye_flat[slot] * (flat_w * keep)[:, None].astype(ye.dtype)
-    y = jnp.zeros((T, d), x.dtype).at[flat_t].add(back)
-
+    with jax.named_scope("moe_router"):
+        probs, top_w, top_i = moe_route(cfg, p["router"], x)
+        mine = (top_i >= held_ids.start) & (top_i < held_ids.stop)
+        held = mine.sum(-1, dtype=jnp.int32)
+        dense = x.shape[0] * x.shape[1] <= MOE_DENSE_MAX_TOKENS
+        if dense:
+            # (B, S, n_held): the token's gate weight for each held expert
+            gate = (jax.nn.one_hot(top_i - held_ids.start, len(held_ids),
+                                   dtype=jnp.float32) * top_w[..., None]).sum(-2)
+    with jax.named_scope("moe_experts"):
+        if dense:
+            y = _held_experts_dense(p, x, gate, act, constrain)
+        else:
+            y = _held_experts_sorted(p, x, top_w, top_i, held_ids.start, act)
     if cfg.n_shared_experts:
-        sp = p["shared"]
-        y = y + (act(x2 @ sp["w_gate"]) * (x2 @ sp["w_up"])) @ sp["w_down"]
-
-    y = y.reshape(B, S, d)
-    if not return_aux:
-        return y
-    # load-balance aux loss (Switch/GShard): E * sum_e f_e * P_e
-    frac_tokens = jnp.mean(jax.nn.one_hot(top_i[:, 0], E, dtype=jnp.float32), axis=0)
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return y, aux
+        with jax.named_scope("moe_shared"):
+            sp = p["shared"]
+            hs = act(proj("bsd,df->bsf", x, sp["w_gate"])) * \
+                proj("bsd,df->bsf", x, sp["w_up"])
+            y = y + proj("bsf,fd->bsd", constrain(hs, "gather"), sp["w_down"])
+    frac_tokens = jnp.mean(jax.nn.one_hot(top_i[..., 0], E, dtype=jnp.float32),
+                           axis=(0, 1))
+    aux = E * jnp.sum(frac_tokens * jnp.mean(probs, axis=(0, 1)))
+    return constrain(y, "gather"), held, aux

@@ -137,10 +137,51 @@ def _dense_block(cfg, lp, x, positions, constrain, *, lora=None, causal=True):
     x = constrain(x + a, "hidden")
     h = L.norm_apply(cfg, lp["mlp_norm"], x)
     if "moe" in lp:
-        m, aux = L.moe_apply(cfg, lp["moe"], h, return_aux=True, constrain=constrain)
+        m, _, aux = L.moe_apply(cfg, lp["moe"], h, constrain)
     else:
         m, aux = L.mlp_apply(cfg, lp["mlp"], h, constrain), jnp.float32(0.0)
     return constrain(x + m, "hidden"), kv, aux
+
+
+def _ffn(cfg, lp, h, constrain):
+    """A block's second half, added to `h`: the norm, then the MLP or the
+    expert layer. Returns (h, held): the expert layer's held assignments
+    per token (B, S), None for an MLP."""
+    hh = L.norm_apply(cfg, lp["mlp_norm"], h)
+    if "moe" in lp:
+        m, held, _ = L.moe_apply(cfg, lp["moe"], hh, constrain)
+        return h + m, held
+    return h + L.mlp_apply(cfg, lp["mlp"], hh, constrain), None
+
+
+def _trunk(cfg, p, x, cache, new_cache, layer, scan):
+    """Scan `layer(lp, h, c0, c1) -> (h, c0, c1, held)` over the decoder
+    stack of a dense / vlm / moe model (a moe model's leading dense layers,
+    then its expert layers), each layer with its slice of the
+    length-indexed caches (`ckv`/`krope` for MLA, else `k`/`v`). Writes the
+    updated caches into `new_cache`; returns (x, held): the held
+    assignments (B, S) summed over the expert layers, or None."""
+    keys = ("ckv", "krope") if cfg.use_mla else ("k", "v")
+
+    def body(h, xs):
+        lp, c0, c1 = xs
+        h, c0, c1, held = layer(lp, h, c0, c1)
+        return h, (c0, c1, held)
+
+    nd = cfg.first_dense_layers if cfg.family == "moe" else 0
+    stacks = [(p["dense_layers"], slice(0, nd)),
+              (p["layers"], slice(nd, None))] if nd else [(p["layers"], None)]
+    outs, held = [], None
+    for stack, sl in stacks:
+        c0, c1 = (cache[k] if sl is None else cache[k][sl] for k in keys)
+        x, (c0, c1, h) = scan(body, x, (stack, c0, c1))
+        outs.append((c0, c1))
+        if h is not None:
+            held = h.sum(0)
+    for j, k in enumerate(keys):
+        parts = [o[j] for o in outs]
+        new_cache[k] = jnp.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+    return x, held
 
 
 def _scan_blocks(cfg, stacked, x, positions, constrain, *, moe, remat, causal=True,
@@ -329,8 +370,11 @@ def init_decode_cache(cfg: ModelConfig, B: int, max_len: int, dtype=None):
 
 
 def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
-                attn_impl=None, unroll=False):
-    """One decode step. token: (B, 1) int32. Returns (logits (B,1,V), cache)."""
+                attn_impl=None, unroll=False, count_held=False):
+    """One decode step. token: (B, 1) int32. Returns (logits (B,1,V), cache).
+    With `count_held`, a model with expert layers also returns
+    cache["moe_held"] (B, 1) int32: each token's top-k picks that fell on
+    the held experts, summed over the expert layers."""
     constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
     pos = cache["pos"]
@@ -351,53 +395,22 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
                                        k_cache=cross_kv[0], v_cache=cross_kv[1],
                                        cross=True, attn_impl=attn_impl)
             h = h + a
-        hh = L.norm_apply(cfg, lp["mlp_norm"], h)
-        if "moe" in lp:
-            h = h + L.moe_apply(cfg, lp["moe"], hh, constrain=constrain)
-        else:
-            h = h + L.mlp_apply(cfg, lp["mlp"], hh, constrain)
-        return h, kc, vc
+        h, held = _ffn(cfg, lp, h, constrain)
+        return h, kc, vc, held
 
     scan = lambda f, init, xs: lax.scan(f, init, xs, unroll=unroll)
+    held = None
     if fam in ("dense", "vlm", "moe"):
         if cfg.use_mla:
-            def body(h, xs):
-                lp, ckv, kr = xs
+            def layer(lp, h, ckv, kr):
                 hh = L.norm_apply(cfg, lp["attn_norm"], h)
                 a, (ckv, kr) = L.mla_decode_apply(cfg, lp["attn"], hh, pos=pos,
                                                   ckv_cache=ckv, krope_cache=kr)
-                h = h + a
-                hh = L.norm_apply(cfg, lp["mlp_norm"], h)
-                if "moe" in lp:
-                    h = h + L.moe_apply(cfg, lp["moe"], hh)
-                else:
-                    h = h + L.mlp_apply(cfg, lp["mlp"], hh)
-                return h, (ckv, kr)
-            nd = cfg.first_dense_layers
-            if fam == "moe" and nd:
-                x, (ckv_d, kr_d) = scan(
-                    body, x, (p["dense_layers"], cache["ckv"][:nd], cache["krope"][:nd]))
-                x, (ckv_m, kr_m) = scan(
-                    body, x, (p["layers"], cache["ckv"][nd:], cache["krope"][nd:]))
-                new_cache["ckv"] = jnp.concatenate([ckv_d, ckv_m], axis=0)
-                new_cache["krope"] = jnp.concatenate([kr_d, kr_m], axis=0)
-            else:
-                x, (ckv, kr) = scan(body, x, (p["layers"], cache["ckv"], cache["krope"]))
-                new_cache["ckv"], new_cache["krope"] = ckv, kr
+                h, held = _ffn(cfg, lp, h + a, constrain)
+                return h, ckv, kr, held
         else:
-            def body(h, xs):
-                lp, kc, vc = xs
-                h, kc, vc = attn_block(lp, h, kc, vc)
-                return h, (kc, vc)
-            nd = cfg.first_dense_layers if fam == "moe" else 0
-            if nd:
-                x, (k_d, v_d) = scan(body, x, (p["dense_layers"], cache["k"][:nd], cache["v"][:nd]))
-                x, (k_m, v_m) = scan(body, x, (p["layers"], cache["k"][nd:], cache["v"][nd:]))
-                new_cache["k"] = jnp.concatenate([k_d, k_m], axis=0)
-                new_cache["v"] = jnp.concatenate([v_d, v_m], axis=0)
-            else:
-                x, (k, v) = scan(body, x, (p["layers"], cache["k"], cache["v"]))
-                new_cache["k"], new_cache["v"] = k, v
+            layer = attn_block
+        x, held = _trunk(cfg, p, x, cache, new_cache, layer, scan)
     elif fam == "ssm":
         def body(h, xs):
             lp, conv, st = xs
@@ -416,7 +429,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
         def super_body(h, xs):
             i, mstack, lora_i, kc, vc, conv_i, ssm_i = xs
             shared = jax.tree.map(lambda a: a[i % cfg.n_shared_attn_blocks], p["shared_blocks"])
-            h, kc, vc = attn_block(shared, h, kc, vc, lora=lora_i)
+            h, kc, vc, _ = attn_block(shared, h, kc, vc, lora=lora_i)
 
             def mamba_body(hh, ys):
                 lp, conv, st = ys
@@ -441,7 +454,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
 
         def body(h, xs):
             lp, kc, vc, ck, cv = xs
-            h, kc, vc = attn_block(lp, h, kc, vc, cross_kv=(ck, cv))
+            h, kc, vc, _ = attn_block(lp, h, kc, vc, cross_kv=(ck, cv))
             return h, (kc, vc)
         x, (k, v) = scan(body, x, (p["dec_layers"], cache["k"], cache["v"],
                                        cache["ck"], cache["cv"]))
@@ -451,6 +464,8 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
     logits = constrain(_logits(cfg, x, head), "logits")
     new_cache["pos"] = pos + 1
+    if count_held and held is not None:
+        new_cache["moe_held"] = held
     return logits, new_cache
 
 
@@ -458,7 +473,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, *, constrain=None,
 
 
 def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
-                  constrain=None, unroll=False):
+                  constrain=None, unroll=False, count_held=False):
     """Advance a decode cache through a C-token prompt chunk.
 
     The chunk's tokens sit at positions [pos, pos+C) where `pos = cache["pos"]`
@@ -481,7 +496,8 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
     (image tokens are prepended, count toward the cache position, and are
     always real — `length` counts text tokens only); encdec requires
     cache["ck"]/["cv"] already populated (see `encode_cross_kv`).
-    Returns (last-position logits (B, 1, V), new cache).
+    Returns (last-position logits (B, 1, V), new cache). `count_held`
+    adds cache["moe_held"] (B, C) as in `decode_step`, padding included.
     """
     constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
@@ -513,53 +529,22 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
                                       k_cache=cross_kv[0], v_cache=cross_kv[1],
                                       cross=True)
             h = h + a
-        hh = L.norm_apply(cfg, lp["mlp_norm"], h)
-        if "moe" in lp:
-            h = h + L.moe_apply(cfg, lp["moe"], hh, constrain=constrain)
-        else:
-            h = h + L.mlp_apply(cfg, lp["mlp"], hh, constrain)
-        return h, kc, vc
+        h, held = _ffn(cfg, lp, h, constrain)
+        return h, kc, vc, held
 
+    held = None
     if fam in ("dense", "vlm", "moe"):
         if cfg.use_mla:
-            def body(h, xs):
-                lp, ckv, kr = xs
+            def layer(lp, h, ckv, kr):
                 hh = L.norm_apply(cfg, lp["attn_norm"], h)
                 a, (ckv, kr) = L.mla_chunk_apply(cfg, lp["attn"], hh,
                                                  start=start, ckv_cache=ckv,
                                                  krope_cache=kr)
-                h = h + a
-                hh = L.norm_apply(cfg, lp["mlp_norm"], h)
-                if "moe" in lp:
-                    h = h + L.moe_apply(cfg, lp["moe"], hh)
-                else:
-                    h = h + L.mlp_apply(cfg, lp["mlp"], hh)
-                return h, (ckv, kr)
-            nd = cfg.first_dense_layers
-            if fam == "moe" and nd:
-                x, (ckv_d, kr_d) = scan(
-                    body, x, (p["dense_layers"], cache["ckv"][:nd], cache["krope"][:nd]))
-                x, (ckv_m, kr_m) = scan(
-                    body, x, (p["layers"], cache["ckv"][nd:], cache["krope"][nd:]))
-                new_cache["ckv"] = jnp.concatenate([ckv_d, ckv_m], axis=0)
-                new_cache["krope"] = jnp.concatenate([kr_d, kr_m], axis=0)
-            else:
-                x, (ckv, kr) = scan(body, x, (p["layers"], cache["ckv"], cache["krope"]))
-                new_cache["ckv"], new_cache["krope"] = ckv, kr
+                h, held = _ffn(cfg, lp, h + a, constrain)
+                return h, ckv, kr, held
         else:
-            def body(h, xs):
-                lp, kc, vc = xs
-                h, kc, vc = attn_block(lp, h, kc, vc)
-                return h, (kc, vc)
-            nd = cfg.first_dense_layers if fam == "moe" else 0
-            if nd:
-                x, (k_d, v_d) = scan(body, x, (p["dense_layers"], cache["k"][:nd], cache["v"][:nd]))
-                x, (k_m, v_m) = scan(body, x, (p["layers"], cache["k"][nd:], cache["v"][nd:]))
-                new_cache["k"] = jnp.concatenate([k_d, k_m], axis=0)
-                new_cache["v"] = jnp.concatenate([v_d, v_m], axis=0)
-            else:
-                x, (k, v) = scan(body, x, (p["layers"], cache["k"], cache["v"]))
-                new_cache["k"], new_cache["v"] = k, v
+            layer = attn_block
+        x, held = _trunk(cfg, p, x, cache, new_cache, layer, scan)
     elif fam == "ssm":
         def body(h, xs):
             lp, conv, st = xs
@@ -580,7 +565,7 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
         def super_body(h, xs):
             i, mstack, lora_i, kc, vc, conv_i, ssm_i = xs
             shared = jax.tree.map(lambda a: a[i % cfg.n_shared_attn_blocks], p["shared_blocks"])
-            h, kc, vc = attn_block(shared, h, kc, vc, lora=lora_i)
+            h, kc, vc, _ = attn_block(shared, h, kc, vc, lora=lora_i)
 
             def mamba_body(hh, ys):
                 lp, conv, st = ys
@@ -606,7 +591,7 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
 
         def body(h, xs):
             lp, kc, vc, ck, cv = xs
-            h, kc, vc = attn_block(lp, h, kc, vc, cross_kv=(ck, cv))
+            h, kc, vc, _ = attn_block(lp, h, kc, vc, cross_kv=(ck, cv))
             return h, (kc, vc)
         x, (k, v) = scan(body, x, (p["dec_layers"], cache["k"], cache["v"],
                                    cache["ck"], cache["cv"]))
@@ -621,11 +606,13 @@ def prefill_chunk(cfg: ModelConfig, params, batch, cache, length=None, *,
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
     logits = constrain(_logits(cfg, x, head), "logits")
     new_cache["pos"] = pos + adv
+    if count_held and held is not None:
+        new_cache["moe_held"] = held
     return logits, new_cache
 
 
 def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
-                 unroll=False):
+                 unroll=False, count_held=False):
     """Speculative-decoding verification forward (DESIGN.md §14).
 
     Advances a decode cache through the C candidate tokens of a draft/verify
@@ -648,6 +635,7 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
     engine verifies all live slots in ONE batched forward, each row's chunk
     at its own decode position. Returns (logits (B, C, V), new_cache, ckpts).
     Rows are independent; callers discard rows/suffixes they reject.
+    `count_held` adds new_cache["moe_held"] (B, C) as in `decode_step`.
     """
     constrain = constrain or L.no_constrain
     p = _cast(params, cfg.dtype)
@@ -674,53 +662,22 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
                                       k_cache=cross_kv[0], v_cache=cross_kv[1],
                                       cross=True)
             h = h + a
-        hh = L.norm_apply(cfg, lp["mlp_norm"], h)
-        if "moe" in lp:
-            h = h + L.moe_apply(cfg, lp["moe"], hh, constrain=constrain)
-        else:
-            h = h + L.mlp_apply(cfg, lp["mlp"], hh, constrain)
-        return h, kc, vc
+        h, held = _ffn(cfg, lp, h, constrain)
+        return h, kc, vc, held
 
+    held = None
     if fam in ("dense", "vlm", "moe"):
         if cfg.use_mla:
-            def body(h, xs):
-                lp, ckv, kr = xs
+            def layer(lp, h, ckv, kr):
                 hh = L.norm_apply(cfg, lp["attn_norm"], h)
                 a, (ckv, kr) = L.mla_chunk_apply(cfg, lp["attn"], hh,
                                                  start=start, ckv_cache=ckv,
                                                  krope_cache=kr)
-                h = h + a
-                hh = L.norm_apply(cfg, lp["mlp_norm"], h)
-                if "moe" in lp:
-                    h = h + L.moe_apply(cfg, lp["moe"], hh)
-                else:
-                    h = h + L.mlp_apply(cfg, lp["mlp"], hh)
-                return h, (ckv, kr)
-            nd = cfg.first_dense_layers
-            if fam == "moe" and nd:
-                x, (ckv_d, kr_d) = scan(
-                    body, x, (p["dense_layers"], cache["ckv"][:nd], cache["krope"][:nd]))
-                x, (ckv_m, kr_m) = scan(
-                    body, x, (p["layers"], cache["ckv"][nd:], cache["krope"][nd:]))
-                new_cache["ckv"] = jnp.concatenate([ckv_d, ckv_m], axis=0)
-                new_cache["krope"] = jnp.concatenate([kr_d, kr_m], axis=0)
-            else:
-                x, (ckv, kr) = scan(body, x, (p["layers"], cache["ckv"], cache["krope"]))
-                new_cache["ckv"], new_cache["krope"] = ckv, kr
+                h, held = _ffn(cfg, lp, h + a, constrain)
+                return h, ckv, kr, held
         else:
-            def body(h, xs):
-                lp, kc, vc = xs
-                h, kc, vc = attn_block(lp, h, kc, vc)
-                return h, (kc, vc)
-            nd = cfg.first_dense_layers if fam == "moe" else 0
-            if nd:
-                x, (k_d, v_d) = scan(body, x, (p["dense_layers"], cache["k"][:nd], cache["v"][:nd]))
-                x, (k_m, v_m) = scan(body, x, (p["layers"], cache["k"][nd:], cache["v"][nd:]))
-                new_cache["k"] = jnp.concatenate([k_d, k_m], axis=0)
-                new_cache["v"] = jnp.concatenate([v_d, v_m], axis=0)
-            else:
-                x, (k, v) = scan(body, x, (p["layers"], cache["k"], cache["v"]))
-                new_cache["k"], new_cache["v"] = k, v
+            layer = attn_block
+        x, held = _trunk(cfg, p, x, cache, new_cache, layer, scan)
     elif fam == "ssm":
         def body(h, xs):
             lp, conv, st = xs
@@ -742,7 +699,7 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
         def super_body(h, xs):
             i, mstack, lora_i, kc, vc, conv_i, ssm_i = xs
             shared = jax.tree.map(lambda a: a[i % cfg.n_shared_attn_blocks], p["shared_blocks"])
-            h, kc, vc = attn_block(shared, h, kc, vc, lora=lora_i)
+            h, kc, vc, _ = attn_block(shared, h, kc, vc, lora=lora_i)
 
             def mamba_body(hh, ys):
                 lp, conv, st = ys
@@ -771,7 +728,7 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
 
         def body(h, xs):
             lp, kc, vc, ck, cv = xs
-            h, kc, vc = attn_block(lp, h, kc, vc, cross_kv=(ck, cv))
+            h, kc, vc, _ = attn_block(lp, h, kc, vc, cross_kv=(ck, cv))
             return h, (kc, vc)
         x, (k, v) = scan(body, x, (p["dec_layers"], cache["k"], cache["v"],
                                    cache["ck"], cache["cv"]))
@@ -781,6 +738,8 @@ def verify_chunk(cfg: ModelConfig, params, batch, cache, *, constrain=None,
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
     logits = constrain(_logits(cfg, x, head), "logits")
     new_cache["pos"] = pos + C
+    if count_held and held is not None:
+        new_cache["moe_held"] = held
     return logits, new_cache, ckpts
 
 

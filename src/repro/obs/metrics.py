@@ -70,6 +70,11 @@ ENGINE_STATS = {
                    "the next phase-program dispatch: no phase program queued"),
     "first_tokens": ("counter", "requests whose first token reached the host"),
     "ttft_s": ("counter", "summed seconds from submit to first token"),
+    "held_assignments": ("counter", "token-to-expert assignments on the "
+                         "experts this device holds, summed over expert "
+                         "layers (real tokens of the paged phases)"),
+    "routed_assignments": ("counter", "all token-to-expert assignments of "
+                           "those tokens"),
 }
 
 FRONTEND_STATS = {

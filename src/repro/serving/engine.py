@@ -303,6 +303,18 @@ class ServingEngine:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.stats = StatsDict(self.metrics, "engine", ENGINE_STATS)
 
+        # expert-layer counters (paged phases): the programs count each
+        # token's top-k picks that fall on the held experts; the host keeps
+        # the real tokens' share at reads it makes anyway
+        moe_layers = cfg.num_layers - cfg.first_dense_layers \
+            if cfg.family == "moe" and cfg.n_experts else 0
+        self._assign_per_token = moe_layers * cfg.moe_top_k
+        self._count_held = self.paged and moe_layers > 0
+        # prefill chunks' held assignments, summed on the device
+        self._held_dev = jnp.zeros((), jnp.uint32) if self._count_held \
+            else None
+        self._held_seen = 0
+
         self.cache = init_decode_cache(cfg, slots, max_len)
         self.cache["pos"] = jnp.zeros((slots,), jnp.int32)
         self._live = np.zeros((slots,), bool)
@@ -517,13 +529,15 @@ class ServingEngine:
             dense = dict(state)
             dense.update(gather_page_views(pools, table))
             logits, new = decode_step(cfg, params, tokens, dense,
-                                      constrain=self._constrain)
+                                      constrain=self._constrain,
+                                      count_held=self._count_held)
             new_state = {k: new[k] for k in state}
             if pools:
                 starts = (state["pos"] // ps) * ps
                 pools = scatter_token_pages(pools, new, write_ids, starts, ps)
-            return (logits, self._with_specs(new_state, self._cache_pspecs),
-                    self._with_specs(pools, self._pool_pspecs))
+            out = (logits, self._with_specs(new_state, self._cache_pspecs),
+                   self._with_specs(pools, self._pool_pspecs))
+            return out + ((new["moe_held"],) if self._count_held else ())
         return step
 
     def _chunk_fn(self, n_ctx: int, nb: int, with_images: bool):
@@ -532,7 +546,8 @@ class ServingEngine:
             cfg, ps = self.cfg, self.page_size
             has_pool = bool(self.alloc.pools)
 
-            def fn(params, state, pools, ctx_ids, tokens, length, write_ids, b0):
+            def fn(params, state, pools, ctx_ids, tokens, length, write_ids,
+                   b0, *held):
                 batch = {"tokens": tokens}
                 if with_images:
                     from repro.models.model import VISION_DIM
@@ -543,12 +558,18 @@ class ServingEngine:
                     dense.update(gather_page_views(pools, ctx_ids[None, :]))
                 logits, new = prefill_chunk(cfg, params, batch, dense,
                                             length=length,
-                                            constrain=self._constrain1)
+                                            constrain=self._constrain1,
+                                            count_held=bool(held))
                 new_state = {k: new[k] for k in state}
                 if has_pool:
                     pools = scatter_chunk_pages(pools, new, write_ids, b0, ps, nb)
-                return logits, new_state, self._with_specs(pools,
-                                                           self._pool_pspecs)
+                out = (logits, new_state,
+                       self._with_specs(pools, self._pool_pspecs))
+                if held:        # add the real tokens' held assignments
+                    real = jnp.arange(tokens.shape[1]) < length
+                    n = jnp.where(real, new["moe_held"][0], 0).sum()
+                    out += (held[0] + n.astype(jnp.uint32),)
+                return out
             self._chunk_fns[key] = _jit_phase(fn, "prefill_chunk",
                                               donate=(2,))
         return self._chunk_fns[key]
@@ -623,13 +644,19 @@ class ServingEngine:
                     ctx, wids = [], []
                 chunk = list(tokens[i:i + true_clen]) + [0] * (cs - true_clen)
                 fn = self._chunk_fn(n_ctx, nb, with_images)
-                logits, state, self.alloc.pools = fn(
+                held = (self._held_dev,) if self._count_held else ()
+                logits, state, self.alloc.pools, *held = fn(
                     self.params, state, self.alloc.pools,
                     jnp.asarray(ctx, jnp.int32),
                     jnp.asarray(chunk, jnp.int32)[None, :],
                     jnp.asarray(true_clen, jnp.int32),
-                    jnp.asarray(wids, jnp.int32), jnp.asarray(b0, jnp.int32))
+                    jnp.asarray(wids, jnp.int32), jnp.asarray(b0, jnp.int32),
+                    *held)
                 self._dispatched()
+                if held:
+                    self._held_dev = held[0]
+                    self.stats["routed_assignments"] += \
+                        self._assign_per_token * true_clen
             self.stats["prefill_invocations"] += 1
             self.stats["prefill_chunks"] += 1
             self.stats["prefill_ctx_positions"] += \
@@ -790,6 +817,11 @@ class ServingEngine:
         with self.tracer.span("engine.first_token", kind="engine", level=2):
             t0 = time.perf_counter()
             nxt = int(jnp.argmax(logits[0, -1]))
+            if self._count_held:        # done with the chunk just read
+                held = int(np.asarray(self._held_dev))
+                self.stats["held_assignments"] += \
+                    (held - self._held_seen) % (1 << 32)
+                self._held_seen = held
             self._synced(t0)
             if not req.retries:         # a requeued request counted already
                 self.stats["first_tokens"] += 1
@@ -859,11 +891,12 @@ class ServingEngine:
                     if b < len(self.slot_pages[s]):
                         write_ids[s] = self.slot_pages[s][b]
             width = min(_pow2_at_least(maxb), self.pages_per_slot)
-            logits, self.cache, self.alloc.pools = self._paged_decode(
+            logits, self.cache, self.alloc.pools, *held = self._paged_decode(
                 self.params, self._tokens, self.cache, self.alloc.pools,
                 self._page_table(width), jnp.asarray(write_ids))
             self._pos_h += 1
         else:
+            held = []
             logits, self.cache = self._decode(self.params, self._tokens, self.cache)
         self._dispatched()
         self.stats["decode_steps"] += 1
@@ -872,6 +905,9 @@ class ServingEngine:
         t0 = time.perf_counter()
         nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
         pos = np.asarray(self.cache["pos"])
+        if held:
+            self._count_round(np.asarray(held[0]),
+                              {s: 1 for s in self.active})
         self._synced(t0, 2)
         for slot, req in list(self.active.items()):
             tok = int(nxt[slot])
@@ -900,13 +936,15 @@ class ServingEngine:
                     dense.update(gather_page_views(pools, ctx_tab))
                 logits, new, ckpts = verify_chunk(cfg, params,
                                                   {"tokens": toks}, dense,
-                                                  constrain=self._constrain)
+                                                  constrain=self._constrain,
+                                                  count_held=self._count_held)
                 new_state = {k: new[k] for k in state}
                 if has_pool:
                     pools = scatter_chunk_pages_rows(pools, new, wtabs, b0s,
                                                      ps, nb)
-                return (logits, self._with_specs(new_state, self._cache_pspecs),
-                        self._with_specs(pools, self._pool_pspecs), ckpts)
+                out = (logits, self._with_specs(new_state, self._cache_pspecs),
+                       self._with_specs(pools, self._pool_pspecs), ckpts)
+                return out + ((new["moe_held"],) if self._count_held else ())
             self._verify_fns[n_ctx] = (
                 _jit_phase(fn, "verify_round", donate=(2,)), nb)
         return self._verify_fns[n_ctx]
@@ -969,12 +1007,13 @@ class ServingEngine:
         if not live:
             return                       # all slots drained; run() reinserts
         if self.paged:
-            logits, new_state, self.alloc.pools, ckpts = fn(
+            logits, new_state, self.alloc.pools, ckpts, *held = fn(
                 self.params, self.cache, self.alloc.pools, ctx,
                 jnp.asarray(toks, jnp.int32), wtabs, b0s)
             cache = dict(self.cache)
             cache.update(new_state)
         else:
+            held = []
             logits, cache, ckpts = self._verify_slab(
                 self.params, jnp.asarray(toks, jnp.int32), self.cache)
             cache = dict(cache)
@@ -985,7 +1024,8 @@ class ServingEngine:
         self.stats["decode_slot_steps"] += len(live)
         self.stats["max_live"] = max(self.stats["max_live"], len(live))
         with self.tracer.span("engine.accept", kind="engine", level=2):
-            self._spec_accept(live, pos_h, drafts, logits, cache, ckpts)
+            self._spec_accept(live, pos_h, drafts, logits, cache, ckpts,
+                              held[0] if held else None)
 
     def _spec_drafts(self):
         """The draft half of a verify round: each live slot's drafts,
@@ -1038,13 +1078,18 @@ class ServingEngine:
                     wtabs[s, j] = pages[b]
         return fn, jnp.asarray(ctx), jnp.asarray(wtabs), jnp.asarray(b0s)
 
-    def _spec_accept(self, live, pos_h, drafts, logits, cache, ckpts):
+    def _spec_accept(self, live, pos_h, drafts, logits, cache, ckpts,
+                     held=None):
         """The accept half of a verify round: read the target's greedy
         tokens back, emit each slot's agreeing prefix plus one, and roll
-        the rejected suffixes back."""
+        the rejected suffixes back. `held`: the round's held assignments per
+        position (slots, C), where the engine counts them."""
         t0 = time.perf_counter()
         Y = np.asarray(jnp.argmax(logits, axis=-1))          # (slots, C)
         nxt = np.asarray(self._tokens[:, 0]).copy()
+        if held is not None:
+            self._count_round(np.asarray(held),
+                              {s: 1 + len(drafts[s]) for s in live})
         self._synced(t0, 2)
         new_pos = pos_h.copy()
         keeps = np.ones((self.slots,), np.int32)
@@ -1102,6 +1147,15 @@ class ServingEngine:
             self._pos_h = new_pos
         self._tokens = jnp.asarray(nxt[:, None], jnp.int32)
         self._note_kv_bytes()
+
+    def _count_round(self, held, tokens: dict) -> None:
+        """Add a decode or verify round's expert-layer counts: `held`
+        (slots, C) from the program, of which the first `tokens[slot]`
+        positions of each slot were real tokens (the rest is padding)."""
+        n = sum(tokens.values())
+        self.stats["held_assignments"] += int(
+            sum(held[s, :k].sum() for s, k in tokens.items()))
+        self.stats["routed_assignments"] += self._assign_per_token * n
 
     def drain_slot(self, slot: int):
         """Evict + requeue (straggler/failure mitigation). Retries are

@@ -10,7 +10,8 @@ chip:
   * the retrieval ranking `l2_rank_device` over >= 256 rows;
   * the serving engine's paged decode step with bf16 params, whose
     argument + temp bytes must fit the chip's 15.75 GiB of HBM;
-  * one chunked-prefill step of the same engine.
+  * one chunked-prefill step of the same engine;
+  * the widest verify round of the `deepseek-v2-lite-16b-ep4` cell.
 
 The topology is described inside a module fixture: only the worker that
 runs this file loads the TPU library.
@@ -113,4 +114,33 @@ def test_full_width_chunked_prefill_compiles(one_chip, engine):
     compiled = eng._chunk_fn(n_ctx, nb, False).lower(
         params, sub, pools, _i32(one_chip, n_ctx), _i32(one_chip, 1, cs),
         _i32(one_chip), _i32(one_chip, nb), _i32(one_chip)).compile()
+    _fits(compiled)
+
+
+def test_deepseek_ep4_verify_round_fits_one_chip(one_chip):
+    """The widest verify round of `deepseek-v2-lite-16b-ep4` (32 slots,
+    1024-token contexts) with its 9.82 GB of weights and the default pool:
+    the largest program of that cell, 10.97 GB of arguments and 3.01 GB of
+    temporaries when this test was written."""
+    from chipbench import harness
+    conf = harness.load_config("deepseek-v2-lite-16b-ep4")
+    cfg = harness.model_config(conf)
+    reference = harness.load_reference(conf)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: reference.make_weights(conf, 1)))
+    slots = conf["serving"]["slots"]
+    eng = ServingEngine(cfg, params, slots=2, max_len=MAX_LEN,
+                        spec_decode="prompt_lookup", num_pages=2 * 64 + 1)
+    num_pages = (slots + 4) * eng.pages_per_slot + 1
+    pools = {k: jax.ShapeDtypeStruct((v.shape[0], num_pages) + v.shape[2:],
+                                     v.dtype, sharding=one_chip)
+             for k, v in eng.alloc.pools.items()}
+    state = {"pos": _i32(one_chip, slots)}
+    n_ctx = eng.pages_per_slot
+    fn, nb = eng._verify_fn(n_ctx)
+    compiled = fn.lower(params, state, pools, _i32(one_chip, slots, n_ctx),
+                        _i32(one_chip, slots, eng.spec_k + 1),
+                        _i32(one_chip, slots, nb), _i32(one_chip, slots)
+                        ).compile()
     _fits(compiled)
